@@ -68,9 +68,11 @@ def kernels_micro() -> List[Row]:
     rows.append(("micro/pallas_sccp_interp/2048", round(t, 1), ka * n * kb))
     key = jnp.asarray(rng.integers(0, 1 << 20, 4096), jnp.int32)
     val = jnp.asarray(rng.standard_normal(4096), jnp.float32)
+    from repro.kernels import platform
     from repro.kernels.bitonic_merge import bitonic_merge_pallas
     t = _timeit(lambda: jax.block_until_ready(
-        bitonic_merge_pallas(key, val)), n=3, warmup=1)
+        bitonic_merge_pallas(key, val, interpret=not platform.on_tpu())),
+        n=3, warmup=1)
     rows.append(("micro/pallas_bitonic_interp/4096", round(t, 1), 4096))
     x = jnp.asarray(rng.standard_normal((n, 128)), jnp.float32)
     t = _timeit(lambda: jax.block_until_ready(
@@ -476,10 +478,11 @@ def dist_spgemm_micro() -> List[Row]:
     from repro.core import ell_cols_from_dense, ell_rows_from_dense
     from repro.core.distributed import (pad_slabs_a, pad_slabs_b, ring_spgemm,
                                         spgemm_coo_sharded)
+    from repro.launch.mesh import make_mesh
     from repro.plan import make_dist_plan
     rows: List[Row] = []
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("ring",))
+    mesh = make_mesh((n_dev,), ("ring",))
     rng = np.random.default_rng(11)
     for tag, n, dens in [("n256", 256, 0.02), ("n512", 512, 0.005)]:
         A = ((rng.random((n, n)) < dens)

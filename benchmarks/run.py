@@ -36,6 +36,8 @@ def main() -> None:
                          " JSON (with metrics merged) to PATH")
     args = ap.parse_args()
     only = set(filter(None, args.only.split(",")))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.trace:
         import repro.obs as obs

@@ -79,7 +79,7 @@ def main():
     # (fake one with XLA_FLAGS=--xla_force_host_platform_device_count=8).
     n_dev = len(jax.devices())
     if n_dev > 1:
-        from repro import make_dist_plan
+        from repro import make_dist_plan, make_mesh
         rng = np.random.default_rng(0)
         n = 128
         a = ((rng.random((n, n)) < 0.05)
@@ -87,7 +87,7 @@ def main():
         at = a.T.copy()
         ea = ell_rows_from_dense(jnp.array(a), max(1, int((a != 0).sum(0).max())))
         eb = ell_cols_from_dense(jnp.array(at), max(1, int((at != 0).sum(1).max())))
-        mesh = jax.make_mesh((n_dev,), ("ring",))
+        mesh = make_mesh((n_dev,), ("ring",))
         dp = make_dist_plan(ea, eb, n_dev=n_dev)
         coo = spgemm(ea, eb, mesh=mesh, axis="ring", dist_plan=dp, check=True)
         ok = np.allclose(np.asarray(coo.to_dense()), a @ at, atol=1e-2)

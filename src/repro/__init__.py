@@ -28,6 +28,7 @@ _NAMES = {
     "make_dist_plan": "repro.plan",
     "make_structure": "repro.plan",
     "make_structure_batched": "repro.plan",
+    "make_mesh": "repro.launch.mesh",
     "plan_spmm_format": "repro.plan",
     "fingerprint": "repro.plan",
     "Plan": "repro.plan",

@@ -28,10 +28,10 @@ Auto-select semantics, in one place (every wrapper follows these rules):
 ``overlap``
     Mesh paths only: ``True`` (default) double-buffers operand rotation —
     each stage's ``ppermute`` prefetch is issued before the current stage's
-    accumulation and rejoined with ``compat.optimization_barrier``, hiding
+    accumulation and rejoined with ``jax.lax.optimization_barrier``, hiding
     communication behind compute. Bit-identical either way.
 ``interpret`` / kernel mode
-    Pallas kernels resolve via ``kernels.bitonic_merge.resolve_mode``:
+    Pallas kernels resolve via ``kernels.platform.resolve_mode``:
     ``None`` → compiled on TPU, XLA realization elsewhere; ``True`` forces
     the interpreter (debug), ``False`` forces compiled Pallas.
 ``batched``

@@ -38,7 +38,7 @@ Three schedules (selected by ``plan.make_dist_plan``):
 All three support ``overlap=True`` double-buffering: each stage's
 ``ppermute`` prefetch of the *next* operand panel is issued before the
 current stage's products are accumulated, and the pair is rejoined with
-``compat.optimization_barrier`` — on hardware with an async ICI the
+``jax.lax.optimization_barrier`` — on hardware with an async ICI the
 exchange hides entirely behind the accumulation scan, and numerics are
 bit-identical either way (the barrier only pins scheduling).
 
@@ -64,7 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import axis_size, optimization_barrier, pvary, shard_map
+from repro.kernels import platform
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs
 
@@ -198,7 +198,7 @@ def spgemm_coo_sharded(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
     ``overlap=True`` (default) double-buffers every schedule's operand
     rotation: the next panel's ``ppermute`` is issued *before* the current
     panel's products are accumulated and the two are rejoined with
-    ``compat.optimization_barrier``, hiding the exchange behind compute on
+    ``jax.lax.optimization_barrier``, hiding the exchange behind compute on
     async-ICI hardware. Purely a scheduling hint — results are bit-identical
     with ``overlap=False`` (which restores accumulate-then-rotate order).
 
@@ -338,9 +338,9 @@ def spgemm_coo_sharded(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
         accumulating device-locally; returns the local sorted Coo.
 
         With ``overlap`` the next panel's ppermute is issued before this
-        panel's products are accumulated; ``optimization_barrier`` rejoins
-        the prefetched buffers with the accumulation result so XLA cannot
-        sink the transfer below the compute it should hide behind.
+        panel's products are accumulated; ``jax.lax.optimization_barrier``
+        rejoins the prefetched buffers with the accumulation result so XLA
+        cannot sink the transfer below the compute it should hide behind.
         """
         if use_stream:
             st0 = streaming.stream_init(streaming.buffer_cap(local_cap),
@@ -352,7 +352,8 @@ def spgemm_coo_sharded(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
                     nbv, nbi = rotate(bv, bi, p)
                     v, r, c = _slab_products(av, ai, bv, bi)
                     st = vb(absorb)(st, r, c, v)
-                    (nbv, nbi), st = optimization_barrier(((nbv, nbi), st))
+                    (nbv, nbi), st = jax.lax.optimization_barrier(
+                        ((nbv, nbi), st))
                 else:
                     v, r, c = _slab_products(av, ai, bv, bi)
                     st = vb(absorb)(st, r, c, v)
@@ -368,7 +369,7 @@ def spgemm_coo_sharded(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
             if overlap:
                 nxt = rotate(bv, bi, p)
                 prod = _slab_products(av, ai, bv, bi)
-                nxt, prod = optimization_barrier((nxt, prod))
+                nxt, prod = jax.lax.optimization_barrier((nxt, prod))
                 return nxt, prod
             prod = _slab_products(av, ai, bv, bi)
             return rotate(bv, bi, p), prod
@@ -429,7 +430,8 @@ def spgemm_coo_sharded(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
                     nbv, nbi = rotate(bv, bi, perm)
                     v, r, c = _slab_products(av, ai, bv, bi)
                     st = vb(absorb)(st, r, c, v)
-                    (nbv, nbi), st = optimization_barrier(((nbv, nbi), st))
+                    (nbv, nbi), st = jax.lax.optimization_barrier(
+                        ((nbv, nbi), st))
                 else:
                     v, r, c = _slab_products(av, ai, bv, bi)
                     st = vb(absorb)(st, r, c, v)
@@ -458,7 +460,7 @@ def spgemm_coo_sharded(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
                     jnp.concatenate([val_b, sq(v)], axis=-1))
                 poison = poison + (blk.ngroups > block_cap).astype(jnp.int32)
                 if overlap:
-                    (nbv, nbi), poison = optimization_barrier(
+                    (nbv, nbi), poison = jax.lax.optimization_barrier(
                         ((nbv, nbi), poison))
                 else:
                     nbv, nbi = rotate(bv, bi, perm)
@@ -478,10 +480,13 @@ def spgemm_coo_sharded(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
     blk_spec = P(axis, *([None] * (1 + int(batched))))
     body = {"ring": shard_ring, "cstat": shard_cstat,
             "summa": shard_summa}[sched]
-    fn = shard_map(
+    # One compiled program (a shard_map called outside jit runs op by op).
+    # Unchecked: the device-local accumulators call Pallas kernels, whose
+    # outputs (and the interpreter off-TPU) carry no varying-axis types.
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_a, spec_a, spec_b, spec_b),
-        out_specs=(blk_spec, blk_spec, blk_spec, P()))
+        out_specs=(blk_spec, blk_spec, blk_spec, P()), check_vma=False))
     if _obs.is_enabled():
         # per-step spans can't escape the shard_map/scan body (it traces
         # once), so the exchange is observed at the dispatch boundary with
@@ -628,7 +633,7 @@ def spgemm_coo_sharded_numeric(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
             v, r, c = v.reshape(-1), r.reshape(-1), c.reshape(-1)
             valid = r >= 0
             pk = jnp.where(valid, r * n_cols + c, 0).astype(jnp.int32)
-            slot = jnp.searchsorted(key, pk, side="left").astype(jnp.int32)
+            slot = platform.searchsorted(key, pk).astype(jnp.int32)
             miss = jnp.logical_or(
                 ~valid, jnp.take(key, jnp.minimum(slot, out_cap - 1),
                                  mode="clip") != pk)
@@ -646,7 +651,7 @@ def spgemm_coo_sharded_numeric(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
                 nbv = jax.lax.ppermute(bv, axis, perm)
                 nbi = jax.lax.ppermute(bi, axis, perm)
                 acc, nm = absorb(acc, nm, bv, bi)
-                (nbv, nbi), (acc, nm) = optimization_barrier(
+                (nbv, nbi), (acc, nm) = jax.lax.optimization_barrier(
                     ((nbv, nbi), (acc, nm)))
             else:
                 acc, nm = absorb(acc, nm, bv, bi)
@@ -655,15 +660,16 @@ def spgemm_coo_sharded_numeric(a: EllRows, b: EllCols, mesh: Mesh, axis: str,
             return (nbv, nbi, acc, nm), ()
 
         init = (b_val, b_idx,
-                pvary(jnp.zeros((out_cap + 1,), acc_dtype), axis),
-                pvary(jnp.zeros((), jnp.int32), axis))
+                jax.lax.pcast(jnp.zeros((out_cap + 1,), acc_dtype), axis,
+                              to="varying"),
+                jax.lax.pcast(jnp.zeros((), jnp.int32), axis, to="varying"))
         (_, _, acc, nm), _ = jax.lax.scan(step, init, None, length=steps)
         return jax.lax.psum(acc, axis), jax.lax.psum(nm, axis)
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(axis, None), P(axis, None),
-                             P(None, axis), P(None, axis), P()),
-                   out_specs=(P(), P()))
+    fn = jax.jit(jax.shard_map(shard_fn, mesh=mesh,
+                               in_specs=(P(axis, None), P(axis, None),
+                                         P(None, axis), P(None, axis), P()),
+                               out_specs=(P(), P())))
     sums, n_miss = fn(a.val, a.idx, b.val, b.idx, st.key)
     from .spgemm import _coo_from_slots, _poison_overflow
     coo = _coo_from_slots(st.key, sums[:out_cap], st.nnz, out_cap=out_cap,
@@ -715,16 +721,17 @@ def ring_spgemm(a: EllRows, b: EllCols, mesh: Mesh, axis: str) -> jax.Array:
             return (b_val_c, b_idx_c, c_acc), ()
 
         init = (b_val, b_idx,
-                pvary(jnp.zeros((n_rows, n_cols), a_val.dtype), axis))
+                jax.lax.pcast(jnp.zeros((n_rows, n_cols), a_val.dtype), axis,
+                              to="varying"))
         (b_val, b_idx, c_acc), _ = jax.lax.scan(step, init, None, length=n_dev)
         return jax.lax.psum(c_acc, axis)
 
     spec_a = P(axis, None)
     spec_b = P(None, axis)
-    fn = shard_map(
+    fn = jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(spec_a, spec_a, spec_b, spec_b),
-        out_specs=P())
+        out_specs=P()))
     return fn(a.val, a.idx, b.val, b.idx)
 
 
@@ -738,7 +745,7 @@ def ring_all_to_all(x: jax.Array, axis: str) -> jax.Array:
     RowClone argument. Used by MoE when ``moe_comm='ring'`` and by the
     B-stationary schedule's owner-binned COO exchange.
     """
-    n_dev = axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     out = jnp.zeros_like(x)
     out = out.at[me].set(x[me])

@@ -39,6 +39,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import platform
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs
 
@@ -379,7 +380,7 @@ def _numeric_scatter(row: jax.Array, col: jax.Array, val: jax.Array,
     pk = jnp.where(valid,
                    row.astype(jnp.int32) * n_cols + col.astype(jnp.int32),
                    0)
-    slot = jnp.searchsorted(key, pk, side="left").astype(jnp.int32)
+    slot = platform.searchsorted(key, pk).astype(jnp.int32)
     miss = jnp.logical_or(~valid, jnp.take(key, jnp.minimum(slot, out_cap - 1),
                                            mode="clip") != pk)
     slot = jnp.where(miss, out_cap, slot)
@@ -414,7 +415,7 @@ def _numeric_stream(a_val, a_idx, b_val, b_idx, key, nnz, *, out_cap: int,
         c = jnp.broadcast_to(b_idx[None, :, :], (group, n, k_b)).reshape(-1)
         valid = jnp.logical_and(r >= 0, c >= 0)
         pk = jnp.where(valid, r * n_cols + c, 0).astype(jnp.int32)
-        slot = jnp.searchsorted(key, pk, side="left").astype(jnp.int32)
+        slot = platform.searchsorted(key, pk).astype(jnp.int32)
         miss = jnp.logical_or(
             ~valid, jnp.take(key, jnp.minimum(slot, out_cap - 1),
                              mode="clip") != pk)

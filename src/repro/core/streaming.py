@@ -14,10 +14,10 @@ Per ``lax.scan`` step over A slab groups:
 
   1. **multiply + sort** — the group's (group, n, k_b) product tile is
      formed, packed into int32 coordinate keys and sorted. On TPU with
-     ``group=1`` this is one fused Pallas kernel
-     (kernels/fused_sccp_stream) so unsorted products never touch HBM;
-     off-TPU the identical contract goes through XLA's fused ``lax.sort``
-     (kernels/ops.fused_slab_sort picks), and the planner sizes ``group``
+     ``group=1`` and a tile that fits one VMEM block this is the Pallas
+     tile kernel (kernels/fused_sccp_stream); otherwise the identical
+     contract goes through XLA's fused ``lax.sort`` (kernels/ops.
+     fused_slab_sort picks), and the planner sizes ``group``
      so the tile amortizes the per-step dispatch floor while staying ≪ the
      full stream.
   2. **compact** — run tails (the tile's unique coordinates with their
@@ -30,7 +30,7 @@ Per ``lax.scan`` step over A slab groups:
   3. **merge** — the compacted tile is merged into the running sorted,
      coalesced buffer and the result compacted back to the buffer width.
      On TPU the merge is the bitonic two-list network
-     (kernels.bitonic_merge.merge_coalesce_pair — reshape/flip partner
+     (kernels.bitonic_merge.merge_coalesce_pair — rotation partner
      exchange, no gathers); off-TPU one fused ``lax.sort`` over the
      concatenated pair realizes the same contract without putting ~100
      dispatch-bound vector ops in the innermost loop. Both lists are
@@ -55,18 +55,13 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import platform
 from repro.kernels.bitonic_merge import (KEY_INVALID, _segmented_total_rows,
                                          merge_coalesce_pair,
                                          next_pot as _pot)
-from repro.kernels.sccp_multiply import auto_interpret
 from repro.obs import trace as _obs
 
 from .formats import Coo, EllCols, EllRows, INVALID
-
-
-def _on_tpu() -> bool:
-    # shared backend detection: the compiled-Pallas predicate, inverted
-    return not auto_interpret()
 
 
 class StreamState(NamedTuple):
@@ -107,7 +102,7 @@ def _coalesce_compact(key: jax.Array, tot: jax.Array, cap: int):
     tail = jnp.logical_and(key != nxt, key != KEY_INVALID)
     csum = jnp.cumsum(tail.astype(jnp.int32))
     n_tail = csum[-1]
-    src = jnp.searchsorted(csum, jnp.arange(1, cap + 1, dtype=jnp.int32))
+    src = platform.searchsorted(csum, jnp.arange(1, cap + 1, dtype=jnp.int32))
     ok = jnp.arange(cap) < jnp.minimum(n_tail, cap)
     src = jnp.minimum(src, key.shape[0] - 1)
     out_key = jnp.where(ok, key[src], KEY_INVALID)
@@ -121,7 +116,7 @@ def _merge_coalesced(key_a, tot_a, key_b, tot_b):
     sorted run-tail-total stream. TPU: the bitonic two-list network
     (no gathers); elsewhere one fused ``lax.sort`` — each key appears at
     most twice, so the run total is one shifted add."""
-    if _on_tpu():
+    if platform.on_tpu():
         return merge_coalesce_pair(key_a, tot_a, key_b, tot_b)
     key = jnp.concatenate([key_a, key_b])
     tot = jnp.concatenate([tot_a, tot_b])
@@ -280,7 +275,7 @@ def spgemm_coo_stream(a: EllRows, b: EllCols, out_cap="auto", *,
     tile_lanes = group * a.n_cols * b.k
     scap = int(stream_cap) if stream_cap else _pot(tile_lanes)
     state0 = stream_init(buffer_cap(out_cap), a.val.dtype)
-    fused = _on_tpu() and group == 1
+    fused = platform.on_tpu() and group == 1
 
     def tile_sorted(g):
         av = jax.lax.dynamic_slice_in_dim(a_val, g * group, group, 0)

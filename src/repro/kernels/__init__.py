@@ -9,7 +9,8 @@
   hash_accum      — per-row-block open-addressing hash accumulation
   insitu_search   — the paper's Algorithm 1 itself (bit-serial minima search)
   ell_spmm        — ELLPACK × dense via one-hot MXU tiles (MoE/SparseLinear)
-  ops             — jit'd public wrappers (padding, fallbacks)
+  ops             — jit'd public wrappers (padding, realization choice)
+  platform        — the one TPU predicate every realization choice reads
   ref             — pure-jnp oracles for every kernel
 """
 from . import ops, ref

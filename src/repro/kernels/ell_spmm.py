@@ -53,7 +53,7 @@ def _ell_spmm_kernel(a_val_ref, a_idx_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "interpret"))
 def ell_spmm_pallas(a_val: jax.Array, a_idx: jax.Array, x: jax.Array,
-                    *, n_rows: int, interpret: bool = True) -> jax.Array:
+                    *, n_rows: int, interpret: bool) -> jax.Array:
     """A(ELLPACK row-wise, (k, n)) @ X(n, d) -> (n_rows, d).
 
     n % BN == 0, n_rows % BM == 0, handled by ops.ell_spmm padding.

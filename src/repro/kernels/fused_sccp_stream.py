@@ -1,23 +1,19 @@
-"""Pallas TPU kernel: fused SCCP slab multiply + in-VMEM tile sort.
+"""One streaming step: SCCP slab multiply + tile sort (Pallas on TPU).
 
-One streaming step of the paper's Fig. 8 iteration realized as a single
-kernel: the products of one A slab against all B slabs are formed, packed
-into coordinate keys and bitonic-sorted **without ever leaving VMEM** — the
-raw (n, k_b) product tile never touches HBM on the compiled path. Output is
-the ``bitonic_merge`` stream contract (ascending keys, invalid lanes parked
-at INT32_MAX, run-tail totals), which the streaming accumulation engine
-(core/streaming.py) compacts and merges into its running buffer.
+One streaming step of the paper's Fig. 8 iteration: the products of one A
+slab against all B slabs are formed and packed into coordinate keys (one
+XLA fusion, 8 B/lane of key+val), then the whole ``pot(n·k_b)`` tile is
+bitonic-sorted and its run totals formed in one VMEM residency by the
+``bitonic_merge`` tile kernel. Output is the ``bitonic_merge`` stream
+contract (ascending keys, invalid lanes parked at INT32_MAX, run-tail
+totals), which the streaming accumulation engine (core/streaming.py)
+compacts and merges into its running buffer.
 
-This is the fusion ``kernels/sccp_multiply.py`` stops short of: that kernel
-emits the raw product tile to HBM (12 B/lane, mostly ELLPACK-padding
-INVALID lanes) for a later global sort; here multiply → pack → sort → run
-totals happen in one VMEM residency, so the per-step HBM traffic is the
-operand slabs in and one sorted pot(n·k_b) stream out.
-
-Off-TPU the same contract is realized by ``fused_slab_sort_xla`` — packed
-keys through XLA's fused ``lax.sort`` plus the log-step segmented total —
-because interpret-mode Pallas would put an interpreter in the innermost
-scan loop (kernels/ops.fused_slab_sort picks per backend).
+The kernel path holds the tile in one block, so it serves tiles of at most
+``bitonic_merge.MAX_KERNEL_TILE`` lanes (kernels/ops.fused_slab_sort
+routes wider tiles, and every tile off-TPU, to ``fused_slab_sort_xla`` —
+packed keys through XLA's fused ``lax.sort`` plus the log-step segmented
+total).
 """
 from __future__ import annotations
 
@@ -25,10 +21,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .bitonic_merge import (KEY_INVALID, _bitonic_sort_rows,
-                            _segmented_total_rows, next_pot as _pot)
+from .bitonic_merge import (KEY_INVALID, _make_sort_kernel,
+                            _segmented_total_rows, next_pot as _pot,
+                            tile_call)
 
 INVALID = -1
 
@@ -37,7 +33,7 @@ def _pack_tile(a_val, a_idx, b_val, b_idx, n_cols: int, pot_len: int):
     """Slab products → packed int32 keys + values, padded to ``pot_len``.
 
     a_val/a_idx: (n,) one A slab; b_val/b_idx: (n, k_b) all B slabs.
-    Shared jnp body of the Pallas kernel and the XLA fallback.
+    Shared by the kernel path and the XLA realization.
     """
     val = a_val[:, None] * b_val                       # (n, k_b)
     row = jnp.broadcast_to(a_idx[:, None], val.shape)
@@ -55,52 +51,25 @@ def _pack_tile(a_val, a_idx, b_val, b_idx, n_cols: int, pot_len: int):
     return key, val
 
 
-def _make_fused_kernel(n_cols: int, pot_len: int):
-    def kernel(a_val_ref, a_idx_ref, b_val_ref, b_idx_ref,
-               key_ref, tot_ref):
-        key, val = _pack_tile(a_val_ref[...].reshape(-1),
-                              a_idx_ref[...].reshape(-1),
-                              b_val_ref[...], b_idx_ref[...],
-                              n_cols, pot_len)
-        key, val = _bitonic_sort_rows(key, val)
-        tot = _segmented_total_rows(key, val)
-        key_ref[...] = key.reshape(key_ref.shape)
-        tot_ref[...] = tot.reshape(tot_ref.shape)
-    return kernel
-
-
+@functools.partial(jax.jit, static_argnames=("n_cols", "interpret"))
 def fused_slab_sort_pallas(a_val: jax.Array, a_idx: jax.Array,
                            b_val: jax.Array, b_idx: jax.Array, *,
-                           n_cols: int, interpret: bool | None = None):
-    """Fused multiply+sort of one slab tile, entirely in VMEM.
+                           n_cols: int, interpret: bool):
+    """Multiply + in-VMEM sort of one slab tile.
 
-    ``a_val``/``a_idx``: (n,) — one A slab; ``b_val``/``b_idx``: (n, k_b).
-    Returns ``(key, tot)`` of length ``pot(n·k_b)``: ascending packed
-    coordinate keys (invalid = INT32_MAX) with run-tail totals.
-    Requires ``n_rows·n_cols < 2³¹`` (packed int32 keys).
-    ``interpret=None`` auto-selects: compiled on TPU, interpreter elsewhere.
+    ``a_val``/``a_idx``: (n,) — one A slab; ``b_val``/``b_idx``: (n, k_b),
+    with ``pot(n·k_b) ≤ MAX_KERNEL_TILE`` on TPU (one block). Returns
+    ``(key, tot)`` of length ``pot(n·k_b)``: ascending packed coordinate
+    keys (invalid = INT32_MAX) with run-tail totals. Requires
+    ``n_rows·n_cols < 2³¹`` (packed int32 keys).
     """
-    if interpret is None:
-        from .sccp_multiply import auto_interpret
-        interpret = auto_interpret()
-    return _fused_slab_sort_jit(a_val, a_idx, b_val, b_idx, n_cols=n_cols,
-                                interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("n_cols", "interpret"))
-def _fused_slab_sort_jit(a_val: jax.Array, a_idx: jax.Array,
-                         b_val: jax.Array, b_idx: jax.Array, *,
-                         n_cols: int, interpret: bool):
     n, k_b = b_val.shape
     pot_len = _pot(n * k_b)
-    # one whole-tile block: slab counts are ELLPACK widths (small), and the
-    # sort network needs the full tile resident anyway
-    return pl.pallas_call(
-        _make_fused_kernel(n_cols, pot_len),
-        out_shape=[jax.ShapeDtypeStruct((pot_len,), jnp.int32),
-                   jax.ShapeDtypeStruct((pot_len,), a_val.dtype)],
-        interpret=interpret,
-    )(a_val, a_idx, b_val, b_idx)
+    key, val = _pack_tile(a_val, a_idx, b_val, b_idx, n_cols, pot_len)
+    key, tot = tile_call(_make_sort_kernel(pot_len), pot_len,
+                         [key.reshape(-1), val.reshape(-1)],
+                         interpret=interpret)
+    return key, tot
 
 
 @functools.partial(jax.jit, static_argnames=("n_cols",))

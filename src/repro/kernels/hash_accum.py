@@ -17,11 +17,11 @@ block-local: per-block tables sorted independently (all blocks ride the batch
 axis of ONE bitonic network, ``bitonic_merge.sort_tiles_pallas``) concatenate
 into a globally sorted stream because block key ranges are disjoint.
 
-Slot assignment is a ``lax.while_loop`` over probe rounds (traced once — the
-0.4.37 toolchain only chokes on gathers repeated across long *unrolled*
-programs): each round gathers the current occupant of every pending product's
-probe slot, claims empty slots with a scatter-min (ties between distinct keys
-racing for one slot resolve to the min; losers probe on), and retires
+Slot assignment is a ``lax.while_loop`` over probe rounds (traced once, so
+its gathers compile once rather than per unrolled round): each round
+gathers the current occupant of every pending product's probe slot, claims
+empty slots with a scatter-min (ties between distinct keys racing for one
+slot resolve to the min; losers probe on), and retires
 products whose slot now holds their key. Values never enter the loop — once
 every product knows its slot, ONE segment_sum accumulates the whole stream.
 
@@ -42,8 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bitonic_merge import (KEY_INVALID, resolve_mode, sort_tiles_pallas,
-                            sort_tiles_xla)
+from .bitonic_merge import KEY_INVALID, sort_tiles
+from .platform import resolve_mode
 
 _EMPTY = KEY_INVALID              # sorts-last sentinel doubles as empty slot
 _HASH_MULT = np.uint32(2654435761)    # Knuth multiplicative (2^32 / phi)
@@ -124,9 +124,5 @@ def _hash_merge_jit(key: jax.Array, val: jax.Array, *, n_blocks: int,
     seg = jnp.where(slot_of >= 0, slot_of, tsize)
     table_val = jax.ops.segment_sum(jnp.where(slot_of >= 0, val, 0), seg,
                                     num_segments=tsize + 1)[:tsize]
-    if mode == "xla":
-        key_s, tot = sort_tiles_xla(table_key, table_val, tile=block_cap)
-    else:
-        key_s, tot = sort_tiles_pallas(table_key, table_val, tile=block_cap,
-                                       interpret=mode == "interpret")
+    key_s, tot = sort_tiles(table_key, table_val, tile=block_cap, mode=mode)
     return key_s, tot, dropped.astype(jnp.int32)

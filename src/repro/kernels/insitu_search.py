@@ -9,11 +9,12 @@ column per step, high→low, keeping only active rows whose current bit is 0
 "if no row's CB stores '1', row DRVs' activation remains the same").
 
 On TPU the word-line parallelism maps to VREG lanes: each of the 32 steps is
-one vectorized mask update over the (n,) tile in VMEM. ``_minima_kernel`` is
-the *faithful* Alg. 1 (mask of argmin rows + iterated extraction);
-``emit_sorted_unique`` batches its emission the way bitonic_merge batches
-the full accumulation — a key-only compare-exchange network produces the
-same sorted-unique key list (Fig. 11c) in one pass instead of nnz_C scans.
+one vectorized mask update over the (n/128, 128) tile in VMEM.
+``_minima_kernel`` is the *faithful* Alg. 1 (mask of argmin rows + iterated
+extraction); ``emit_sorted_unique`` batches its emission the way
+bitonic_merge batches the full accumulation — a key-only compare-exchange
+network produces the same sorted-unique key list (Fig. 11c) in one pass
+instead of nnz_C scans.
 ``align_keys`` is the second half of the paper's in-situ search: every
 product coordinate is located in that sorted list by a gather-free
 vectorized search (a CAM lookup on hardware; here a broadcast compare /
@@ -27,13 +28,14 @@ explicit ``interpret=True`` reserves for kernel-correctness tests.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bitonic_merge import _partner, next_pot, resolve_mode
+from .bitonic_merge import (LANES, _make_key_sort_kernel, _merge_pairs,
+                            next_pot, tile_call)
+from .platform import resolve_mode
 
 KEY_INVALID = jnp.iinfo(jnp.int32).max
 
@@ -41,32 +43,38 @@ KEY_INVALID = jnp.iinfo(jnp.int32).max
 # compared per inner loop iteration (both VMEM-tile sized).
 _ALIGN_TILE = 512
 _ALIGN_CHUNK = 512
+# The CAM-style kernel compares every product with every unique key, so it
+# serves short unique lists only; longer lists take the O(log u)
+# ``searchsorted`` realization on every backend.
+ALIGN_MAX_KEYS = 1 << 14
 
 
 def _minima_kernel(v_ref, mask_ref):
     v = v_ref[...]
-    active = v != KEY_INVALID                         # all valid rows (line 3)
+    # masks ride as int32 0/1: Mosaic carries no boolean vectors in loops
+    active = (v != KEY_INVALID).astype(jnp.int32)     # all valid rows (line 3)
 
     def bit_step(i, active):
         bit = 30 - i                                  # non-negative int32 keys
-        zero_bit = jnp.logical_and(active,
-                                   jnp.bitwise_and(v >> bit, 1) == 0)
-        any_zero = jnp.any(zero_bit)
+        zero_bit = active * (1 - jnp.bitwise_and(v >> bit, 1))
+        any_zero = jnp.max(zero_bit) > 0
         # Alg. 1 line 8: keep '0'-bit rows iff some row had a '0' here
         return jnp.where(any_zero, zero_bit, active)
 
-    active = jax.lax.fori_loop(0, 31, bit_step, active)
-    mask_ref[...] = active
+    mask_ref[...] = jax.lax.fori_loop(0, 31, bit_step, active)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _minima_mask_pallas_jit(v: jax.Array, *, interpret: bool) -> jax.Array:
     (n,) = v.shape
-    return pl.pallas_call(
+    npad = (-n) % LANES
+    v2 = jnp.pad(v, (0, npad), constant_values=KEY_INVALID).reshape(-1, LANES)
+    mask = pl.pallas_call(
         _minima_kernel,
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
+        out_shape=jax.ShapeDtypeStruct(v2.shape, jnp.int32),
         interpret=interpret,
-    )(v)
+    )(v2)
+    return mask.reshape(-1)[:n] != 0
 
 
 @jax.jit
@@ -129,74 +137,20 @@ def search_emit_sorted(v: jax.Array, max_unique: int,
 # ---------------------------------------------------------------------------
 
 
-def _sort_keys_rows(key: jax.Array) -> jax.Array:
-    """Full ascending bitonic sort along the last axis — the key-only half
-    of bitonic_merge's network (no value lane to carry: emission only needs
-    the keys, alignment recovers each product's slot afterwards)."""
-    n = key.shape[-1]
-    steps = int(math.log2(n))
-    lane = jnp.arange(n, dtype=jnp.int32)
-    for stage in range(steps):
-        up = (jnp.bitwise_and(lane, 1 << (stage + 1)) == 0)
-        for sub in range(stage, -1, -1):
-            d = 1 << sub
-            is_lo = (jnp.bitwise_and(lane, d) == 0)
-            keep_min = jnp.logical_xor(is_lo, jnp.logical_not(up))
-            pk = _partner(key, d)
-            key = jnp.where(keep_min, jnp.minimum(key, pk),
-                            jnp.maximum(key, pk))
-    return key
-
-
-def _merge_keys_rows(key: jax.Array) -> jax.Array:
-    """Ascending merge of *bitonic* rows: the final log₂ n stages only."""
-    n = key.shape[-1]
-    steps = int(math.log2(n))
-    lane = jnp.arange(n, dtype=jnp.int32)
-    for sub in range(steps - 1, -1, -1):
-        d = 1 << sub
-        keep_min = (jnp.bitwise_and(lane, d) == 0)
-        pk = _partner(key, d)
-        key = jnp.where(keep_min, jnp.minimum(key, pk), jnp.maximum(key, pk))
-    return key
-
-
-def _make_emit_sort_kernel(tile: int):
-    def kernel(key_ref, out_ref):
-        key = key_ref[...].reshape(-1, tile)
-        out_ref[...] = _sort_keys_rows(key).reshape(out_ref.shape)
-    return kernel
-
-
-def _make_emit_merge_kernel(run: int):
-    def kernel(key_ref, out_ref):
-        key = key_ref[...].reshape(-1, 2, run)
-        # ascending ++ descending = bitonic, then one merge-network pass
-        key = jnp.concatenate(
-            [key[:, 0, :], jnp.flip(key[:, 1, :], axis=-1)], axis=-1)
-        out_ref[...] = _merge_keys_rows(key).reshape(out_ref.shape)
-    return kernel
-
-
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def _emit_sort_keys_pallas(key: jax.Array, *, tile: int,
                            interpret: bool) -> jax.Array:
-    """Globally sort a power-of-2 key stream: one network per VMEM tile,
-    then pairwise key-only merges up the tree (bitonic_merge's blocking)."""
+    """Globally sort a power-of-2 key stream: the key-only bitonic network
+    per VMEM tile (no value lane to carry: emission only needs the keys,
+    alignment recovers each product's slot afterwards), then pairwise
+    key-only merges up the tree as XLA ops (bitonic_merge's blocking)."""
     (n,) = key.shape
     t = min(tile, n)
-    key = pl.pallas_call(
-        _make_emit_sort_kernel(t),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=interpret,
-    )(key)
+    (key,) = tile_call(_make_key_sort_kernel(t), t, [key],
+                       interpret=interpret)
     run = t
     while run < n:
-        key = pl.pallas_call(
-            _make_emit_merge_kernel(run),
-            out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-            interpret=interpret,
-        )(key)
+        key, _ = _merge_pairs(key, None, run)
         run *= 2
     return key
 
@@ -267,22 +221,20 @@ def emit_sorted_unique(key: jax.Array, out_cap: int, *,
 
 def _make_align_kernel(u: int, chunk: int):
     def kernel(pk_ref, uk_ref, slot_ref, hit_ref):
-        pk = pk_ref[...]
-        uk = uk_ref[...]
+        pk = pk_ref[...]                              # (bt, 1) products
 
         def body(j, carry):
             slot, hit = carry
-            ukc = jax.lax.dynamic_slice_in_dim(uk, j * chunk, chunk)
-            # CAM-style broadcast compare: no gathers, the (tile, chunk)
+            ukc = uk_ref[:, pl.ds(pl.multiple_of(j * chunk, chunk), chunk)]
+            # CAM-style broadcast compare: no gathers, the (bt, chunk)
             # compare matrix lives entirely in VREGs
-            lt = jnp.sum((ukc[None, :] < pk[:, None]).astype(jnp.int32),
-                         axis=1)
-            eq = jnp.any(ukc[None, :] == pk[:, None], axis=1)
-            return slot + lt, jnp.logical_or(hit, eq)
+            lt = jnp.sum((ukc < pk).astype(jnp.int32), axis=1, keepdims=True)
+            eq = jnp.max((ukc == pk).astype(jnp.int32), axis=1,
+                         keepdims=True)
+            return slot + lt, jnp.maximum(hit, eq)
 
-        slot0 = jnp.zeros(pk.shape, jnp.int32)
-        hit0 = jnp.zeros(pk.shape, jnp.bool_)
-        slot, hit = jax.lax.fori_loop(0, u // chunk, body, (slot0, hit0))
+        zero = jnp.zeros(pk.shape, jnp.int32)
+        slot, hit = jax.lax.fori_loop(0, u // chunk, body, (zero, zero))
         slot_ref[...] = slot
         hit_ref[...] = hit
     return kernel
@@ -294,17 +246,17 @@ def _align_keys_pallas_jit(pk: jax.Array, uk: jax.Array, *, interpret: bool):
     (u,) = uk.shape
     bt = min(_ALIGN_TILE, n)
     chunk = min(_ALIGN_CHUNK, u)
-    return pl.pallas_call(
+    col = pl.BlockSpec((bt, 1), lambda i: (i, 0))
+    slot, hit = pl.pallas_call(
         _make_align_kernel(u, chunk),
         grid=(n // bt,),
-        in_specs=[pl.BlockSpec((bt,), lambda i: (i,)),
-                  pl.BlockSpec((u,), lambda i: (0,))],
-        out_specs=[pl.BlockSpec((bt,), lambda i: (i,)),
-                   pl.BlockSpec((bt,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32),
-                   jax.ShapeDtypeStruct((n,), jnp.bool_)],
+        in_specs=[col, pl.BlockSpec((1, u), lambda i: (0, 0))],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((n, 1), jnp.int32)],
         interpret=interpret,
-    )(pk, uk)
+    )(pk.reshape(n, 1), uk.reshape(1, u))
+    return slot.reshape(n), hit.reshape(n) != 0
 
 
 @jax.jit
@@ -328,9 +280,11 @@ def align_keys(pk: jax.Array, uk: jax.Array, *,
     CAM lookup per product, here one vectorized gather-free pass per
     realization. KEY_INVALID padding in ``uk`` is harmless by construction
     (it is never < a valid key, and only KEY_INVALID product lanes — which
-    callers mask — can equal it)."""
+    callers mask — can equal it). The broadcast-compare kernel costs
+    O(n·u), so lists longer than ``ALIGN_MAX_KEYS`` take ``searchsorted``
+    on TPU as well."""
     mode = resolve_mode(interpret)
-    if mode == "xla":
+    if mode == "xla" or (mode == "pallas" and uk.shape[0] > ALIGN_MAX_KEYS):
         return align_keys_xla(pk, uk)
     (n,) = pk.shape
     bt = min(_ALIGN_TILE, next_pot(max(1, n)))

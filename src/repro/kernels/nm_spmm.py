@@ -31,8 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bitonic_merge import resolve_mode
 from .ops import pad_to
+from .platform import resolve_mode
 
 BT = 128   # token tile
 BR = 128   # condensed-row tile (must be a multiple of N)
@@ -48,14 +48,17 @@ def _nm_spmm_kernel(x_ref, val_ref, off_ref, o_ref, *, n: int, m: int):
     x = x_ref[...]                          # (BT, BR·m/n) dense window cols
     val = val_ref[...]                      # (BR, D) condensed values
     off = off_ref[...].astype(jnp.int32)    # (BR, D) within-window offsets
-    bt = x.shape[0]
-    windows = BR // n
-    xw = x.reshape(bt, windows, m)
+    bx = x.shape[1]
+    # window column of every condensed row: selection matrices on the MXU
+    # stand in for the strided relayout Mosaic cannot lower
+    col = jax.lax.broadcasted_iota(jnp.int32, (bx, BR), 0)
+    base = (jax.lax.broadcasted_iota(jnp.int32, (bx, BR), 1) // n) * m
     acc = jnp.zeros(o_ref.shape, jnp.float32)
     for s in range(m):                      # static unroll over offsets
         # window column s, repeated N× to line up with condensed rows
-        xs = jnp.broadcast_to(xw[:, :, s][:, :, None],
-                              (bt, windows, n)).reshape(bt, BR)
+        # (exact: one 1 per selection column)
+        xs = jnp.dot(x, (col == base + s).astype(x.dtype),
+                     preferred_element_type=jnp.float32).astype(x.dtype)
         vs = jnp.where(off == s, val.astype(jnp.float32), 0.0)
         acc = acc + jnp.dot(xs, vs, preferred_element_type=jnp.float32)
     o_ref[...] += acc.astype(o_ref.dtype)
@@ -65,7 +68,7 @@ def _nm_spmm_kernel(x_ref, val_ref, off_ref, o_ref, *, n: int, m: int):
                    static_argnames=("n", "m", "d_in", "interpret"))
 def nm_spmm_pallas(x: jax.Array, val: jax.Array, off: jax.Array,
                    *, n: int, m: int, d_in: int,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """X(t, d_in) × condensed N:M planes (R, d_out) -> (t, d_out).
 
     t % BT == 0, R % BR == 0 (window-aligned), handled by nm_spmm padding.

@@ -1,9 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handle padding/alignment (lane tiles multiple of 128, power-of-2 merge
-tiles), choose interpret mode off-TPU, and fall back to the jnp reference
-where a kernel's structural preconditions can't be met (e.g. coordinate
-space too large for 32-bit packed keys).
+tiles), pick the realization from ``platform.on_tpu`` and the tile width a
+kernel block can hold, and raise where a kernel's structural preconditions
+can't be met (e.g. coordinate space too large for 32-bit packed keys).
 """
 from __future__ import annotations
 
@@ -12,16 +12,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import fused_sccp_stream, hash_accum, insitu_search, radix_bucket
-from .bitonic_merge import KEY_INVALID, bitonic_merge_pallas, sort_merge_tree_pallas
+from . import (fused_sccp_stream, hash_accum, insitu_search, platform,
+               radix_bucket)
+from .bitonic_merge import (KEY_INVALID, MAX_KERNEL_TILE, next_pot,
+                            sort_merge_tree_pallas)
 from .ell_spmm import BM, BN, ell_spmm_pallas
 from .sccp_multiply import LANE_BLOCK, sccp_multiply_pallas
 
 INVALID = -1
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def pad_to(x: jax.Array, axis: int, mult: int, fill):
@@ -46,23 +44,25 @@ def sccp_multiply(a_val, a_idx, b_val, b_idx, *, block_n: int | None = None):
     b_val_p = pad_to(b_val, 0, bn, 0)
     b_idx_p = pad_to(b_idx, 0, bn, INVALID)
     val, row, col = sccp_multiply_pallas(
-        a_val_p, a_idx_p, b_val_p, b_idx_p, block_n=bn)  # interpret auto
+        a_val_p, a_idx_p, b_val_p, b_idx_p, block_n=bn,
+        interpret=not platform.on_tpu())
     return val[:, :n, :], row[:, :n, :], col[:, :n, :]
 
 
 def fused_slab_sort(a_val, a_idx, b_val, b_idx, *, n_cols: int):
     """One streaming step: slab products → sorted packed keys + run totals.
 
-    On TPU the fused Pallas kernel keeps the raw product tile in VMEM
-    (kernels/fused_sccp_stream); elsewhere the identical contract goes
-    through XLA's fused sort — NOT interpret-mode Pallas, which would put an
-    interpreter inside the streaming engine's innermost scan loop.
-    Coordinate spaces ≥ 2³¹ can't pack (callers route those to the unpacked
-    two-key 'sort' path, as spgemm_coo does automatically).
+    On TPU, tiles of at most ``MAX_KERNEL_TILE`` lanes are sorted in VMEM by
+    the Pallas tile kernel (kernels/fused_sccp_stream); wider tiles, and
+    every tile elsewhere, go through XLA's fused sort — never interpret-mode
+    Pallas, which would put an interpreter inside the streaming engine's
+    innermost scan loop. Coordinate spaces ≥ 2³¹ can't pack (callers route
+    those to the unpacked two-key 'sort' path, as spgemm_coo does
+    automatically).
     """
-    if _on_tpu():
+    if platform.on_tpu() and next_pot(b_val.size) <= MAX_KERNEL_TILE:
         return fused_sccp_stream.fused_slab_sort_pallas(
-            a_val, a_idx, b_val, b_idx, n_cols=n_cols)  # interpret auto
+            a_val, a_idx, b_val, b_idx, n_cols=n_cols, interpret=False)
     return fused_sccp_stream.fused_slab_sort_xla(
         a_val, a_idx, b_val, b_idx, n_cols=n_cols)
 
@@ -80,13 +80,16 @@ def sort_merge(row, col, val, n_rows: int, n_cols: int, *, tile: int = 4096):
     streams go through the multi-tile merge tree (sort VMEM-sized tiles
     independently, pairwise-merge sorted runs up the tree) so the k_a·n·k_b
     product stream never has to fit one monolithic power-of-two network.
+    On TPU the tile is capped at ``MAX_KERNEL_TILE`` (one VMEM block).
     """
     packed = _packed_stream(row, col, val, n_rows, n_cols)
     if packed is None:
         _unpackable(n_rows, n_cols)
     key, val = packed
-    return sort_merge_tree_pallas(key, val, tile=tile,
-                                  interpret=not _on_tpu())
+    tpu = platform.on_tpu()
+    return sort_merge_tree_pallas(
+        key, val, tile=min(tile, MAX_KERNEL_TILE) if tpu else tile,
+        interpret=not tpu)
 
 
 def _packed_stream(row, col, val, n_rows: int, n_cols: int):
@@ -222,6 +225,6 @@ def ell_spmm(a_val, a_idx, x, n_rows: int, *, d_chunk: int = 512):
     for lo in range(0, d, d_chunk):
         xc = x_p[:, lo:lo + d_chunk]
         outs.append(ell_spmm_pallas(a_val_p, a_idx_p, xc, n_rows=m_pad,
-                                    interpret=not _on_tpu()))
+                                    interpret=not platform.on_tpu()))
     out = jnp.concatenate(outs, axis=-1) if len(outs) > 1 else outs[0]
     return out[:n_rows]

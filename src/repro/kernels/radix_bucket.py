@@ -10,9 +10,9 @@ replaces the global pass with two bandwidth-friendly ones:
      that owns its output-row range and writes it at ``(bucket, rank)`` where
      ``rank`` is the running per-bucket count. Ranks come from a chunked scan
      carrying one (n_buckets,) counter vector (``bin_ranks_pallas``): each
-     chunk does a one-hot cumsum in VMEM, gather-free — the rank readback is a
-     masked row-sum, not a dynamic gather (the 0.4.37 toolchain compiles 1-D
-     gathers over long unrolled programs in minutes).
+     chunk does a one-hot cumsum in VMEM (one triangular matmul on the MXU),
+     gather-free — the rank readback is a masked row-sum, not a dynamic
+     gather.
   2. **Per-bucket sort+coalesce** — every bucket is a power-of-2 tile, so ALL
      buckets ride the batch axis of ONE bitonic network
      (``bitonic_merge.sort_tiles_pallas``), working-set bounded by
@@ -39,64 +39,67 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .bitonic_merge import (KEY_INVALID, resolve_mode, sort_tiles_pallas,
-                            sort_tiles_xla)
+from .bitonic_merge import KEY_INVALID, sort_tiles
+from .platform import resolve_mode
 
-_RANK_CHUNK = 1024
+_RANK_CHUNK = 256
 
 
 def _make_rank_kernel(n_buckets: int, chunk: int):
-    """Per-element rank within its bucket via a chunked one-hot cumsum scan.
+    """Per-element rank within its bucket, one chunk per grid step.
 
-    Carry is the (n_buckets,) element count seen so far; within a chunk the
-    inclusive one-hot cumsum gives local ranks and the rank readback is a
-    masked row-sum (no gather). Invalid lanes (bid < 0) match no one-hot
-    column and rank -1, which the binning scatter parks in the dump slot.
+    A VMEM scratch row carries the per-bucket element count seen so far;
+    within a chunk the inclusive one-hot cumsum is one lower-triangular
+    matmul on the MXU (float32 counts ≤ 2²⁴ are exact) and the rank
+    readback is a masked row-sum (no gather). Invalid lanes (bid < 0) match
+    no one-hot column and rank -1, which the binning scatter parks in the
+    dump slot.
     """
-    def kernel(bid_ref, rank_out_ref):
-        bid = bid_ref[...].reshape(-1, chunk)
-        ids = jnp.arange(n_buckets, dtype=jnp.int32)
+    def kernel(bid_ref, rank_out_ref, count_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            count_ref[...] = jnp.zeros_like(count_ref)
 
-        def step(carry, bchunk):
-            oh = (bchunk[:, None] == ids[None, :]).astype(jnp.int32)
-            incl = jnp.cumsum(oh, axis=0) + carry[None, :]
-            rank = jnp.sum(oh * incl, axis=1) - 1
-            return carry + jnp.sum(oh, axis=0), rank
-
-        _, ranks = jax.lax.scan(step, jnp.zeros((n_buckets,), jnp.int32), bid)
-        rank_out_ref[...] = ranks.reshape(rank_out_ref.shape)
+        bid = bid_ref[...]                                    # (chunk, 1)
+        ids = jax.lax.broadcasted_iota(jnp.int32, (1, n_buckets), 1)
+        oh = (bid == ids).astype(jnp.float32)                 # (chunk, nb)
+        r = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        tril = (c <= r).astype(jnp.float32)
+        incl = jnp.dot(tril, oh, preferred_element_type=jnp.float32)
+        incl = incl + count_ref[...]
+        rank = jnp.sum(oh * incl, axis=1, keepdims=True) - 1.0
+        rank_out_ref[...] = rank.astype(jnp.int32)
+        count_ref[...] += jnp.sum(oh, axis=0, keepdims=True)
     return kernel
 
 
+@functools.partial(jax.jit, static_argnames=("n_buckets", "interpret"))
 def bin_ranks_pallas(bid: jax.Array, *, n_buckets: int,
-                     interpret: bool | None = None) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """Stable-binning ranks: rank[i] = #{j <= i : bid[j] == bid[i]} - 1.
 
     ``bid`` int32 (-1 = invalid, yields rank -1); length must be a multiple
     of the scan chunk (callers pad — product streams are already padded to a
-    power of two for the sort stage). ``interpret=None`` (default)
-    auto-selects: compiled on TPU, interpreter elsewhere (the XLA
-    realization is ``bin_ranks_xla``; ``bucket_merge`` picks it
-    automatically off-TPU).
+    power of two for the sort stage). The XLA realization is
+    ``bin_ranks_xla``; ``bucket_merge`` picks per ``resolve_mode``.
     """
-    if interpret is None:
-        from .sccp_multiply import auto_interpret
-        interpret = auto_interpret()
-    return _bin_ranks_jit(bid, n_buckets=n_buckets, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("n_buckets", "interpret"))
-def _bin_ranks_jit(bid: jax.Array, *, n_buckets: int,
-                   interpret: bool) -> jax.Array:
     (n,) = bid.shape
     chunk = min(_RANK_CHUNK, n)
     assert n % chunk == 0, (n, chunk)
-    return pl.pallas_call(
+    col = pl.BlockSpec((chunk, 1), lambda i: (i, 0))
+    rank = pl.pallas_call(
         _make_rank_kernel(n_buckets, chunk),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        grid=(n // chunk,),
+        in_specs=[col],
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, n_buckets), jnp.float32)],
         interpret=interpret,
-    )(bid)
+    )(bid.reshape(n, 1))
+    return rank.reshape(n)
 
 
 @functools.partial(jax.jit, static_argnames=("n_buckets",))
@@ -174,10 +177,6 @@ def _bucket_merge_jit(key: jax.Array, val: jax.Array, *, n_buckets: int,
                   .at[dst].set(jnp.where(in_cap, val, 0))[:dump])
     dropped = jnp.sum(jnp.logical_and(valid, jnp.logical_not(in_cap)))
 
-    if mode == "xla":
-        key_s, tot = sort_tiles_xla(binned_key, binned_val, tile=bucket_cap)
-    else:
-        key_s, tot = sort_tiles_pallas(binned_key, binned_val,
-                                       tile=bucket_cap,
-                                       interpret=mode == "interpret")
+    key_s, tot = sort_tiles(binned_key, binned_val, tile=bucket_cap,
+                            mode=mode)
     return key_s, tot, dropped.astype(jnp.int32)

@@ -41,36 +41,14 @@ def _sccp_kernel(a_val_ref, a_idx_ref, b_val_ref, b_idx_ref,
     col_ref[...] = jnp.where(ok, col, INVALID)
 
 
-def auto_interpret() -> bool:
-    """Interpret only where the Pallas TPU lowering is unavailable.
-
-    The compiled path is the point of writing kernels; interpret mode is the
-    CPU/debug fallback, orders of magnitude slower. Resolved at trace time,
-    so jitted callers bake in the right choice for the backend they compile
-    for.
-    """
-    return jax.default_backend() != "tpu"
-
-
+@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def sccp_multiply_pallas(a_val: jax.Array, a_idx: jax.Array,
                          b_val: jax.Array, b_idx: jax.Array,
-                         *, block_n: int = LANE_BLOCK,
-                         interpret: bool | None = None):
+                         *, block_n: int = LANE_BLOCK, interpret: bool):
     """Tiled SCCP multiply. Shapes: a (k_a, n), b (n, k_b); n % block_n == 0.
 
-    Returns (val, row, col) each (k_a, n, k_b). ``interpret=None`` (default)
-    auto-selects: compiled on TPU, interpreter elsewhere (``auto_interpret``).
+    Returns (val, row, col) each (k_a, n, k_b).
     """
-    if interpret is None:
-        interpret = auto_interpret()
-    return _sccp_multiply_jit(a_val, a_idx, b_val, b_idx,
-                              block_n=block_n, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def _sccp_multiply_jit(a_val: jax.Array, a_idx: jax.Array,
-                       b_val: jax.Array, b_idx: jax.Array,
-                       *, block_n: int, interpret: bool):
     k_a, n = a_val.shape
     n2, k_b = b_val.shape
     assert n == n2, (n, n2)

@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512").strip()
-# ^ MUST precede any other import: jax locks the device count on first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any other import: jax locks the device count on first init,
+# and this sweep compiles for 512 fake host devices, never for a chip.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -32,7 +34,6 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis
 from repro.configs import ARCHS, applicable_shapes, get_config, get_shape
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (abstract_decode_args, abstract_prefill_args,
@@ -124,7 +125,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: Path,
         lowered = fn.lower(*args)
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
     coll, coll_count = collective_bytes(hlo)
     # trip-count-aware analysis (HloCostAnalysis counts while bodies once —

@@ -10,6 +10,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.parallel.sharding import sharding_rules
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--model-parallel", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch + ("-smoke" if args.smoke else ""))
     model = build_model(cfg)

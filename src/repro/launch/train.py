@@ -11,6 +11,7 @@ import argparse
 import jax
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 from repro.optim import AdamWConfig
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--no-resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     name = args.arch + ("-smoke" if args.smoke else "")
     cfg = get_config(name)

@@ -26,7 +26,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.obs import trace as _obs
 from repro.parallel.sharding import maybe_shard
 
@@ -147,8 +146,8 @@ def _spmm_ell_auto(a, x):
     MXU tiles on TPU (kernels/ell_spmm.py via ops.ell_spmm), the XLA
     segment-sum realization elsewhere — the resolve_mode convention applied
     to the structured multiply."""
-    from repro.kernels import ops
-    if ops._on_tpu():
+    from repro.kernels import ops, platform
+    if platform.on_tpu():
         return ops.ell_spmm(a.val, a.idx, x, a.n_rows)
     from repro.core.spgemm import spmm_ell_dense
     return spmm_ell_dense(a, x)
@@ -249,7 +248,7 @@ def _moe_sort(p, x_grp, cfg, dtype):
         return _moe_sort_body(x_loc, router, wg, wu, wd, cfg, dtype,
                               gaxes, model_axes)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(gspec[0], None, None), P(), wg_spec, wg_spec, wd_spec),
         out_specs=(P(gspec[0], None, None), P()),

@@ -24,7 +24,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import optimization_barrier
 from repro.parallel.sharding import maybe_shard
 
 from . import attention as attn
@@ -297,8 +296,8 @@ def decoder_forward(params, tokens, cfg, *, prefix_embed=None,
             # barrier pins per-iteration consumption of the remat-saved carry
             # so XLA cannot hoist a whole-stack fp32 convert out of the
             # backward loop (16.5 GiB/device on mistral-123b; §Perf iter 1);
-            # compat wrapper keeps it differentiable on jax 0.4.x
-            x = optimization_barrier(x)
+            # its VJP applies the barrier to the cotangent as well
+            x = jax.lax.optimization_barrier(x)
             aux_seg = jnp.zeros((), jnp.float32)
             cache_u = {}
             for i, kind in enumerate(unit):

@@ -284,16 +284,13 @@ def sync(x):
     arrays (tracers pass through untouched). Returns ``x``."""
     if not _tracer._enabled:
         return x
-    try:
-        import jax
-        for leaf in jax.tree_util.tree_leaves(x):
-            if isinstance(leaf, jax.core.Tracer):
-                continue
-            blk = getattr(leaf, "block_until_ready", None)
-            if blk is not None:
-                blk()
-    except Exception:
-        pass
+    import jax
+    for leaf in jax.tree_util.tree_leaves(x):
+        if isinstance(leaf, jax.core.Tracer):
+            continue
+        blk = getattr(leaf, "block_until_ready", None)
+        if blk is not None:
+            blk()
     return x
 
 

@@ -13,8 +13,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 
 def compress_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     scale = jnp.max(jnp.abs(x)) / 127.0 + 1e-12
@@ -31,7 +29,7 @@ def compressed_psum_mean(grads, axis: str, error=None):
 
     Returns (mean_grads, new_error). Call inside shard_map over ``axis``.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         g = g + (e if e is not None else 0.0)

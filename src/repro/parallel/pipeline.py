@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import pvary, shard_map
-
 
 def pipeline_apply(stage_fn: Callable, params_stacked, x_microbatches,
                    mesh: Mesh, axis: str = "pipe"):
@@ -62,15 +60,16 @@ def pipeline_apply(stage_fn: Callable, params_stacked, x_microbatches,
             return (buf, outs), ()
 
         (buf, outs), _ = jax.lax.scan(
-            tick, (pvary(buf, axis), pvary(outs, axis)),
+            tick, (jax.lax.pcast(buf, axis, to="varying"),
+                   jax.lax.pcast(outs, axis, to="varying")),
             jnp.arange(total))
         # outs live on the last stage; broadcast to all for a replicated out
         outs = jax.lax.psum(
             jnp.where(stage == n_stages - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(axis), P()),
-        out_specs=P())
+        out_specs=P()))
     return fn(params_stacked, x_microbatches)

@@ -199,19 +199,19 @@ class StructureCache:
             kw["tile"] = 4096
         times: Dict[str, float] = {}
         plans: Dict[str, Plan] = {}
+        packable = a.n_rows * b.n_cols < 2 ** 31 - 1
         for bk in self.autotune_backends:
-            try:
-                p = make_plan(a, b, backend=bk, **kw)
-                run = lambda: jax.block_until_ready(
-                    spgemm_coo(a, b, plan=p).val)
-                run()  # compile + warm
-                t0 = time.perf_counter()
-                for _ in range(self.probe_iters):
-                    run()
-                times[bk] = (time.perf_counter() - t0) / self.probe_iters
-                plans[bk] = p
-            except Exception:  # backend inapplicable here → not a candidate
-                continue
+            if bk != "sort" and not packable:
+                continue  # packed-key backend cannot span this space
+            p = make_plan(a, b, backend=bk, **kw)
+            run = lambda: jax.block_until_ready(
+                spgemm_coo(a, b, plan=p).val)
+            run()  # compile + warm
+            t0 = time.perf_counter()
+            for _ in range(self.probe_iters):
+                run()
+            times[bk] = (time.perf_counter() - t0) / self.probe_iters
+            plans[bk] = p
         if not times:
             return make_plan(a, b, **kw)
         winner = min(times, key=times.get)

@@ -24,8 +24,9 @@ The model is also **memory-aware**: every backend's modeled intermediate
 bytes go into ``Plan.est`` (``interm_*`` — the materialized un-accumulated
 product lanes, the quantity SpGEMM is bound by per Liu & Vinter / Nagasaka
 et al.), and when the op-count winner's intermediate exceeds
-``mem_budget`` bytes the planner overrides it with ``'stream'``, whose
-intermediate does not grow with ``k_a``.
+``mem_budget`` bytes the planner overrides it with the cheapest backend
+that fits — ``'stream'``, whose intermediate does not grow with ``k_a``,
+when none of the materializing ones does.
 
 ``make_plan`` runs the symbolic phase (plan/symbolic) on concrete operands,
 derives ``out_cap`` and every backend's blocking sizes from *exact*
@@ -55,6 +56,7 @@ import numpy as np
 
 from repro.core.formats import EllCols, EllRows
 from repro.core.hwmodel import MatrixStats, splim_latency, stats_from_ell
+from repro.kernels import platform
 from repro.kernels.bitonic_merge import next_pot as _pot
 from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs
@@ -93,10 +95,23 @@ SCAN_STEP_C = 16384.0
 STREAM_TILE_TARGET = 32768
 STREAM_INTERM_MARGIN = 4.0
 
-# Default intermediate-bytes budget before the planner forces 'stream':
-# 1 GiB of materialized product lanes comfortably fits HBM/host RAM for the
-# toy suites, while genuinely large k_a·n·k_b streams blow past it.
+# Intermediate-bytes budget on backends that report no device memory (the
+# CPU host): 1 GiB of materialized product lanes comfortably fits host RAM
+# for the toy suites, while genuinely large k_a·n·k_b streams blow past it.
 DEFAULT_MEM_BUDGET = 1 << 30
+# Where the device reports its memory (TPU HBM), the budget is this share
+# of it: the modeled intermediate counts only the un-accumulated product
+# lanes, and the sort that consumes them needs about as much again in
+# temporaries, plus the output buffers.
+DEVICE_MEM_SHARE = 4
+
+
+def default_mem_budget() -> int:
+    """Intermediate-bytes budget for the default device."""
+    stats = jax.devices()[0].memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"]) // DEVICE_MEM_SHARE
+    return DEFAULT_MEM_BUDGET
 
 
 def _net_cost(n: int, length: int) -> float:
@@ -226,7 +241,7 @@ def _backend_interm_bytes(stream_lanes: int, stream_pot: int,
 def make_plan(a: EllRows, b: EllCols, *, out_cap: Optional[int] = None,
               backend: Optional[str] = None, exact: bool = True,
               tile: int = 4096, slack: float = 1.0,
-              mem_budget: int = DEFAULT_MEM_BUDGET) -> Plan:
+              mem_budget: Optional[int] = None) -> Plan:
     """Symbolic phase + backend selection on concrete (non-traced) operands.
 
     ``out_cap``/``backend`` pin the respective decision while the planner
@@ -234,10 +249,14 @@ def make_plan(a: EllRows, b: EllCols, *, out_cap: Optional[int] = None,
     ``exact=False`` degrades the symbolic phase to the cheap row-flop upper
     bound (sizes stay safe: caps come from product histograms, which
     dominate unique-coordinate histograms). ``mem_budget`` bounds the
-    modeled materialized-intermediate bytes: when the op-count winner would
-    materialize more, ``'stream'`` (whose intermediate is O(n·k_b), not
-    O(k_a·n·k_b)) is chosen instead.
+    modeled materialized-intermediate bytes (default ``default_mem_budget``:
+    a share of the device's memory, 1 GiB where none is reported): when the
+    op-count winner would materialize more, the cheapest backend within the
+    budget is chosen instead — ``'stream'`` (whose intermediate is O(n·k_b),
+    not O(k_a·n·k_b)) when no materializing backend fits.
     """
+    if mem_budget is None:
+        mem_budget = default_mem_budget()
     if backend is not None and backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected {BACKENDS}")
     n_rows, n_cols, n = a.n_rows, b.n_cols, a.n_cols
@@ -248,7 +267,7 @@ def make_plan(a: EllRows, b: EllCols, *, out_cap: Optional[int] = None,
             "two-key path) spans it")
     stream = a.k * n * b.k
     stream_pot = _pot(stream)
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = platform.on_tpu()
     slab_lanes = n * b.k
 
     # --- symbolic phase -----------------------------------------------------
@@ -321,10 +340,13 @@ def make_plan(a: EllRows, b: EllCols, *, out_cap: Optional[int] = None,
                                        n_blocks, block_cap, int(out_cap))
         chosen = min(costs, key=costs.get)
         # memory-aware override: a winner that must materialize more
-        # intermediate bytes than the budget loses to the streaming engine,
-        # whose working set does not grow with k_a.
-        if interm[chosen] > mem_budget and interm["stream"] < interm[chosen]:
-            chosen = "stream"
+        # intermediate bytes than the budget loses to the cheapest backend
+        # within it, else to the smallest working set (the streaming
+        # engine's, which does not grow with k_a).
+        if interm[chosen] > mem_budget:
+            fits = [k for k in costs if interm[k] <= mem_budget]
+            chosen = (min(fits, key=costs.get) if fits
+                      else min(interm, key=interm.get))
         if n_rows * n_cols >= 2 ** 31 - 1:
             chosen = "sort"                 # only unpacked keys span the space
         est = {f"cost_{k}": v for k, v in costs.items()}

@@ -12,12 +12,14 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 300):
     """Run a python snippet in a subprocess with N fake host devices
     (jax locks the device count at first init, so multi-device tests need
     their own process)."""
-    env = {"XLA_FLAGS": f"--xla_force_host_platform_device_count={n_devices}",
-           "PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin"}
     import os
-    env.update({k: v for k, v in os.environ.items()
-                if k not in env and k != "XLA_FLAGS"})
-    env["PYTHONPATH"] = str(REPO / "src")
+    env = dict(os.environ)
+    env.update({"XLA_FLAGS":
+                f"--xla_force_host_platform_device_count={n_devices}",
+                "PYTHONPATH": str(REPO / "src"),
+                # the fake devices are host devices; never the chip, which
+                # the test process itself may hold
+                "JAX_PLATFORMS": "cpu"})
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout)
     if out.returncode != 0:

@@ -16,7 +16,8 @@ A = ((rng.random((n,n)) < 0.25) * rng.standard_normal((n,n))).astype(np.float32)
 B = ((rng.random((n,n)) < 0.25) * rng.standard_normal((n,n))).astype(np.float32)
 a = ell_rows_from_dense(jnp.array(A), 16)
 b = ell_cols_from_dense(jnp.array(B), 16)
-mesh = jax.make_mesh((8,), ("ring",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("ring",))
 C = ring_spgemm(a, b, mesh, "ring")
 np.testing.assert_allclose(np.asarray(C), A@B, atol=1e-4)
 print("OK")
@@ -28,12 +29,12 @@ def test_ring_all_to_all_matches_transpose():
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.distributed import ring_all_to_all
-mesh = jax.make_mesh((8,), ("ring",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("ring",))
 x = jnp.arange(8*8*4, dtype=jnp.float32).reshape(8, 8, 4)
-out = shard_map(lambda xs: ring_all_to_all(xs[0], "ring")[None],
-                mesh=mesh, in_specs=P("ring"), out_specs=P("ring"))(x)
+out = jax.shard_map(lambda xs: ring_all_to_all(xs[0], "ring")[None],
+                    mesh=mesh, in_specs=P("ring"), out_specs=P("ring"))(x)
 np.testing.assert_allclose(np.asarray(out), np.asarray(jnp.swapaxes(x, 0, 1)))
 print("OK")
 """)
@@ -52,7 +53,8 @@ from repro.parallel.sharding import sharding_rules
 import dataclasses
 cfg = dataclasses.replace(get_config("granite-moe-3b-a800m").reduced(),
                           d_model=64, vocab=256)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 model = build_model(cfg)
 with sharding_rules(mesh), mesh:
     params = model.init(jax.random.PRNGKey(0))
@@ -78,7 +80,8 @@ model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)}
 l1 = float(model.loss(params, batch))
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, 8), ("data", "model"))
 with sharding_rules(mesh), mesh:
     l8 = float(jax.jit(model.loss)(params, batch))
 np.testing.assert_allclose(l1, l8, rtol=2e-2)
@@ -102,7 +105,8 @@ model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)}
 l1 = float(model.loss(params, batch))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 with sharding_rules(mesh), mesh:
     l8 = float(jax.jit(model.loss)(params, batch))
 np.testing.assert_allclose(l1, l8, rtol=2e-2)
@@ -115,15 +119,15 @@ def test_compressed_psum_mean_8dev():
 import warnings; warnings.filterwarnings("ignore")
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.optim import compressed_psum_mean
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 g = jnp.linspace(-1, 1, 8*32).reshape(8, 32).astype(jnp.float32)
 def f(gs):
     mean, err = compressed_psum_mean({"g": gs[0]}, "data")
     return mean["g"][None], err["g"][None]
-mean, err = shard_map(f, mesh=mesh, in_specs=P("data"),
-                      out_specs=P("data"))(g)
+mean, err = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                          out_specs=P("data"))(g)
 true = np.asarray(g).mean(0)
 got = np.asarray(mean)[0]
 np.testing.assert_allclose(got, true, atol=0.02)

@@ -25,7 +25,9 @@ from repro.core import (ell_rows_from_dense, ell_cols_from_dense, spgemm_coo,
                         spgemm_coo_sharded, AccumulatorOverflow)
 from repro.plan import make_dist_plan
 
-mesh = jax.make_mesh((8,), ("ring",))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((8,), ("ring",))
 rng = np.random.default_rng(0)
 
 def env_grid():

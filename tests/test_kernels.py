@@ -36,10 +36,12 @@ def test_sccp_kernel_sweep(rng, ka, n, kb, dtype):
 
 
 def test_sccp_interpret_auto_select(rng, monkeypatch):
-    """sccp_multiply_pallas defaults to the COMPILED path when the backend
-    supports Pallas lowering (TPU) and to the interpreter elsewhere — the
-    old hardcoded interpret=True would run the interpreter on real TPUs."""
+    """ops.sccp_multiply runs the COMPILED kernel when the platform
+    predicate says TPU and the interpreter elsewhere — the kernel itself
+    takes ``interpret`` as a required argument, so no caller can reach the
+    interpreter on a TPU by omission."""
     import repro.kernels.sccp_multiply as sm
+    from repro.kernels import platform
     seen = {}
     real = sm.pl.pallas_call
 
@@ -51,14 +53,13 @@ def test_sccp_interpret_auto_select(rng, monkeypatch):
     monkeypatch.setattr(sm.pl, "pallas_call", spy)
     ins = list(map(jnp.asarray, _ell_inputs(rng, 2, 128, 2)))
 
-    assert sm.auto_interpret() is True       # this host has no TPU
-    sm.sccp_multiply_pallas(*ins, block_n=128)
+    assert platform.on_tpu() is False        # this host has no TPU
+    ops.sccp_multiply(*ins, block_n=128)
     assert seen["interpret"] is True         # auto → interpreter off-TPU
 
-    monkeypatch.setattr(sm.jax, "default_backend", lambda: "tpu")
-    assert sm.auto_interpret() is False
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     ins2 = list(map(jnp.asarray, _ell_inputs(rng, 3, 128, 2)))  # fresh trace
-    got = sm.sccp_multiply_pallas(*ins2, block_n=128)
+    got = ops.sccp_multiply(*ins2, block_n=128)
     assert seen["interpret"] is False        # auto → compiled on TPU
     exp = ref.sccp_multiply_ref(*ins2)
     for g, e in zip(got, exp):
@@ -347,9 +348,8 @@ def test_bucket_interpret_auto_select(rng, monkeypatch):
     """bucket_merge mirrors sccp's auto-select: the XLA realization
     (bin_ranks_xla + sort_tiles_xla, zero pallas_call) off-TPU, the compiled
     Pallas kernels (interpret=False) when the backend is TPU."""
-    import repro.kernels.bitonic_merge as bm
     import repro.kernels.radix_bucket as rb
-    import repro.kernels.sccp_multiply as sm
+    from repro.kernels import platform
     seen = []
     real = rb.pl.pallas_call          # pl is the shared pallas module
 
@@ -360,7 +360,7 @@ def test_bucket_interpret_auto_select(rng, monkeypatch):
 
     monkeypatch.setattr(rb.pl, "pallas_call", spy)
 
-    assert bm.resolve_mode(None) == "xla"       # this host has no TPU
+    assert platform.resolve_mode(None) == "xla"  # this host has no TPU
     k, v = _packed_stream(rng, 512)
     key_x, tot_x, drop_x = rb.bucket_merge(
         k, v, n_buckets=4, bucket_cap=512, keys_per_bucket=1024)
@@ -374,8 +374,8 @@ def test_bucket_interpret_auto_select(rng, monkeypatch):
     assert int(drop_x) == int(di)
 
     seen.clear()
-    monkeypatch.setattr(sm.jax, "default_backend", lambda: "tpu")
-    assert bm.resolve_mode(None) == "pallas"
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    assert platform.resolve_mode(None) == "pallas"
     k2, v2 = _packed_stream(rng, 1024)          # fresh shape → fresh trace
     rb.bucket_merge(k2, v2, n_buckets=4, bucket_cap=1024, keys_per_bucket=1024)
     assert seen and all(i is False for i in seen)   # compiled on TPU
@@ -386,7 +386,7 @@ def test_hash_interpret_auto_select(rng, monkeypatch):
     final table sort switches between sort_tiles_xla and compiled Pallas."""
     import repro.kernels.bitonic_merge as bm
     import repro.kernels.hash_accum as ha
-    import repro.kernels.sccp_multiply as sm
+    from repro.kernels import platform
     seen = []
     real = bm.pl.pallas_call          # hash_accum's only Pallas use is the
                                       # bitonic_merge sort stage
@@ -401,7 +401,7 @@ def test_hash_interpret_auto_select(rng, monkeypatch):
     # shapes deliberately distinct from the bucket test's: the shared
     # sort_tiles_pallas jit cache would otherwise satisfy identical
     # signatures without re-tracing, blinding the spy
-    assert bm.resolve_mode(None) == "xla"
+    assert platform.resolve_mode(None) == "xla"
     k, v = _packed_stream(rng, 512)
     key_x, tot_x, drop_x = ha.hash_merge(
         k, v, n_blocks=4, block_cap=256, keys_per_block=1024)
@@ -415,7 +415,7 @@ def test_hash_interpret_auto_select(rng, monkeypatch):
     assert int(drop_x) == int(di)
 
     seen.clear()
-    monkeypatch.setattr(sm.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
     k2, v2 = _packed_stream(rng, 1024)
     ha.hash_merge(k2, v2, n_blocks=8, block_cap=256, keys_per_block=512)
     assert seen and all(i is False for i in seen)

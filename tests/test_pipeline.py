@@ -21,7 +21,9 @@ ref = x
 for s in range(n_stages):
     ref = stage_fn({"w": params["w"][s], "b": params["b"][s]}, ref)
 
-mesh = jax.make_mesh((8,), ("pipe",))
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((8,), ("pipe",))
 out = pipeline_apply(stage_fn, params, x, mesh, axis="pipe")
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 print("OK")

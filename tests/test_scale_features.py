@@ -29,7 +29,8 @@ opt = {{"step": jnp.array(3, jnp.int32)}}
 mgr.save(1, params, opt)
 
 # restore onto a 4x2 mesh with the leaf sharded over 'a'
-mesh = jax.make_mesh((4, 2), ("a", "b"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("a", "b"))
 sh = {{"w": NamedSharding(mesh, P("a", "b"))}}
 osh = {{"step": NamedSharding(mesh, P())}}
 p2, o2, _ = mgr.restore(1, params, opt, shardings=(sh, osh))
@@ -85,9 +86,9 @@ def test_dryrun_entrypoint_single_cell(tmp_path):
     import os, subprocess, sys, json
     from pathlib import Path
     repo = Path(__file__).resolve().parents[1]
-    # inherit the environment (like conftest.run_with_devices): dropping
-    # e.g. JAX_PLATFORMS would make jax probe hardware plugins and hang
-    env = dict(os.environ, PYTHONPATH=str(repo / "src"))
+    # the dry run compiles for fake host devices: it pins JAX_PLATFORMS=cpu
+    # itself, and the child is given it too so it never reaches for a chip
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"), JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)   # dryrun sets its own device count
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch",

@@ -176,8 +176,8 @@ def test_search_interpret_auto_select(monkeypatch):
     (minima_mask_xla / jnp.sort / searchsorted, zero pallas_call) off-TPU,
     the compiled Pallas kernels (interpret=False) when the backend is TPU;
     explicit interpret=True reserves the interpreter for kernel tests."""
-    import repro.kernels.bitonic_merge as bm
     import repro.kernels.insitu_search as isrch
+    from repro.kernels import platform
     seen = []
     real = isrch.pl.pallas_call
 
@@ -188,7 +188,7 @@ def test_search_interpret_auto_select(monkeypatch):
 
     monkeypatch.setattr(isrch.pl, "pallas_call", spy)
 
-    assert bm.resolve_mode(None) == "xla"       # this host has no TPU
+    assert platform.resolve_mode(None) == "xla"  # this host has no TPU
     rng = np.random.default_rng(6)
     k = jnp.asarray(rng.integers(0, 4096, 512), jnp.int32)
     uk_x, nnz_x = isrch.emit_sorted_unique(k, 64)
@@ -208,8 +208,8 @@ def test_search_interpret_auto_select(monkeypatch):
     np.testing.assert_array_equal(np.asarray(mask_x), np.asarray(mask_i))
 
     seen.clear()
-    monkeypatch.setattr(isrch.jax, "default_backend", lambda: "tpu")
-    assert bm.resolve_mode(None) == "pallas"
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    assert platform.resolve_mode(None) == "pallas"
     k2 = jnp.asarray(rng.integers(0, 4096, 1024), jnp.int32)  # fresh traces
     uk2, _ = isrch.emit_sorted_unique(k2, 128)
     isrch.align_keys(k2, uk2)

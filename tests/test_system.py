@@ -106,3 +106,30 @@ def test_splim_beats_coo_splim_everywhere():
         t = hwmodel.splim_latency(s)["total"]
         t_coo = hwmodel.coo_splim_latency(s)["total"]
         assert t < t_coo, (s.n, t, t_coo)
+
+
+@pytest.mark.parametrize("env_dir", [None, "custom"])
+def test_compile_cache_location(tmp_path, env_dir):
+    """Entry points keep compiled programs where JAX_COMPILATION_CACHE_DIR
+    says, else in ``<repo>/.jax_cache``; importing repro sets no cache."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    repo = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"), JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = str(repo / ".jax_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    code = ("import jax, repro\n"
+            "before = jax.config.jax_compilation_cache_dir\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "used = enable_compile_cache()\n"
+            "print(before, used, jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    before, used, after = out.stdout.split()
+    assert used == after == want
+    assert before == (want if env_dir else "None")
