@@ -97,54 +97,6 @@ def rows(pattern: str = "*.json"):
     return recs
 
 
-def measured_rows():
-    """Measured roofline per accumulation backend — the ``--only roofline``
-    suite of benchmarks/run.py, built on ``repro.obs.roofline``.
-
-    Per backend one evidence row ``micro/roofline_<backend>/<tag>``:
-    ``us_per_call`` is the span-measured time of one jitted ``spgemm_coo``
-    call, ``derived`` the achieved-vs-reference bandwidth fraction
-    (modeled bytes from the planner's ``interm_*`` estimates over a
-    measured streaming-copy anchor, see obs/roofline.py). CI gates
-    derived ∈ (0, 1.5] for all six backends. One extra
-    ``micro/roofline_ref_bw/<tag>`` row records the anchor itself (GB/s in
-    the derived column) so trajectory regressions are attributable.
-
-    When results/dryrun/*.json artifacts exist (repro.launch.dryrun), the
-    static HLO analysis rows are appended as ``model/roofline/<...>``;
-    absent artifacts are skipped silently — the measured rows never depend
-    on them.
-    """
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.core import ell_cols_from_dense, ell_rows_from_dense
-    from repro.obs import roofline as rl
-    out = []
-    rng = np.random.default_rng(17)
-    ref_bw = rl.measure_reference_bw()
-    for tag, n, dens in [("n128", 128, 0.05)]:
-        A = ((rng.random((n, n)) < dens)
-             * rng.standard_normal((n, n))).astype(np.float32)
-        B = ((rng.random((n, n)) < dens)
-             * rng.standard_normal((n, n))).astype(np.float32)
-        ka = max(1, int((A != 0).sum(0).max()))
-        kb = max(1, int((B != 0).sum(1).max()))
-        a = ell_rows_from_dense(jnp.asarray(A), ka)
-        b = ell_cols_from_dense(jnp.asarray(B), kb)
-        res = rl.measure_roofline(a, b, ref_bw=ref_bw)
-        out.append((f"micro/roofline_ref_bw/{tag}", 0.0,
-                    round(ref_bw / 1e9, 3)))
-        for bk, r in res.items():
-            out.append((f"micro/roofline_{bk}/{tag}", round(r["us"], 1),
-                        round(r["frac"], 6)))
-    for r in rows():                      # dryrun artifacts, when present
-        out.append((f"model/roofline/{r['arch']}-{r['shape']}-{r['mesh']}",
-                    round(r["t_compute_s"] * 1e6, 3),
-                    round(r["roofline_fraction"], 4)))
-    return out
-
-
 def to_markdown(recs) -> str:
     hdr = ("| arch | shape | mesh | compute s | memory s | collective s | "
            "dominant | useful | advice |\n|---|---|---|---|---|---|---|---|---|\n")

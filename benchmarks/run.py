@@ -28,7 +28,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma list: table1,fig14..fig19,micro,accum,"
                          "accum-backends,plan-cache,serve-sparse,dist,"
-                         "dist-2d,moe,lm,roofline")
+                         "dist-2d,moe,lm")
     ap.add_argument("--json", default="", metavar="PATH",
                     help="also write collected rows as JSON to PATH")
     ap.add_argument("--trace", default="", metavar="PATH",
@@ -45,7 +45,6 @@ def main() -> None:
 
     from . import paper_figures as pf
     from . import microbench as mb
-    from . import roofline as rl
 
     suites = [
         ("table1", pf.table1),
@@ -65,7 +64,6 @@ def main() -> None:
         ("dist-2d", mb.dist2d_micro),
         ("moe", mb.moe_dispatch_micro),
         ("lm", mb.lm_step_micro),
-        ("roofline", rl.measured_rows),
     ]
     collected = []
     print("name,us_per_call,derived")

@@ -23,6 +23,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace as _obs
+
 from .formats import Coo, INVALID
 
 
@@ -80,9 +82,16 @@ def merge_sorted(row: jax.Array, col: jax.Array, val: jax.Array,
 
 def accumulate(row: jax.Array, col: jax.Array, val: jax.Array,
                out_cap: int, n_rows: int, n_cols: int) -> Coo:
-    """sort + merge: the full in-situ-search-equivalent accumulation."""
-    r, c, v = sort_by_coords(row, col, val, n_rows)
-    return merge_sorted(r, c, v, out_cap, n_rows, n_cols)
+    """sort + merge: the full in-situ-search-equivalent accumulation.
+
+    Instrumented (repro.obs): ``spgemm.accumulate.sort`` and
+    ``spgemm.accumulate.merge`` spans, each closing on a device sync while
+    tracing is on (outside jit both halves run op by op, so the host span
+    is what bounds their device time)."""
+    with _obs.span("spgemm.accumulate.sort", lanes=int(row.size)):
+        r, c, v = _obs.sync(sort_by_coords(row, col, val, n_rows))
+    with _obs.span("spgemm.accumulate.merge", out_cap=int(out_cap)):
+        return _obs.sync(merge_sorted(r, c, v, out_cap, n_rows, n_cols))
 
 
 def check_no_overflow(coo: Coo) -> Coo:
@@ -103,7 +112,6 @@ def check_no_overflow(coo: Coo) -> Coo:
         where = "" if ngroups.ndim == 0 else f" in {n_bad} batch entr{'y' if n_bad == 1 else 'ies'}"
         # exactly one event per offending call (not per batch entry)
         from repro.obs import metrics as _obs_metrics
-        from repro.obs import trace as _obs
         _obs_metrics.inc("spgemm.overflow_events")
         _obs.instant("spgemm.overflow", worst=worst, cap=cap, n_bad=n_bad)
         raise AccumulatorOverflow(

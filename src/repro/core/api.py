@@ -48,6 +48,8 @@ from __future__ import annotations
 
 from typing import Optional
 
+from repro.obs import trace as _obs
+
 from .formats import Coo, EllCols, EllRows
 
 
@@ -78,7 +80,31 @@ def spgemm(a: EllRows, b: EllCols, *, structure=None, mesh=None,
     Kwargs not consumed by the selected variant (e.g. ``schedule`` without a
     mesh) are ignored only when they hold their defaults; see the module
     docstring for the shared auto-select semantics.
+
+    Instrumented (repro.obs): one ``spgemm.call`` root span per call, with
+    the product-stream ``lanes`` and, for a concrete result, its ``nnz``.
     """
+    kw = dict(structure=structure, mesh=mesh, axis=axis, batched=batched,
+              out_cap=out_cap, accumulator=accumulator, schedule=schedule,
+              tile=tile, plan=plan, dist_plan=dist_plan, overlap=overlap,
+              stream_cap=stream_cap, group=group, check=check,
+              validate=validate)
+    if not _obs.is_enabled():
+        return _route(a, b, **kw)
+    import jax
+    import numpy as np
+    with _obs.call("spgemm.call",
+                   lanes=int(a.val.size * b.val.shape[-1])) as sp:
+        out = _route(a, b, **kw)
+        if out.ngroups is not None and not isinstance(out.ngroups,
+                                                      jax.core.Tracer):
+            sp.set(nnz=int(np.sum(jax.device_get(out.ngroups))))
+    return out
+
+
+def _route(a: EllRows, b: EllCols, *, structure, mesh, axis, batched,
+           out_cap, accumulator, schedule, tile, plan, dist_plan, overlap,
+           stream_cap, group, check, validate) -> Coo:
     if axis is not None and mesh is None:
         raise ValueError("axis= requires mesh= (a jax.sharding.Mesh)")
     if mesh is not None and axis is None:

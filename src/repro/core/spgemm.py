@@ -363,6 +363,24 @@ def _coo_from_slots(key: jax.Array, sums: jax.Array, nnz: jax.Array, *,
                ngroups=nnz.astype(jnp.int32))
 
 
+@partial(jax.jit, static_argnames=("out_cap",))
+def _search_slots(key: jax.Array, pk: jax.Array, valid: jax.Array, *,
+                  out_cap: int):
+    """Slot of each packed product key among the structure's sorted unique
+    keys, under the ``numeric.search`` scope. Invalid lanes and keys absent
+    from the structure go to the dump slot ``out_cap``; returns the slots
+    and the number of valid misses. Jitted on its own, the search is a
+    function of its own in the lowered module, and so in the key of the
+    persistent compile cache, which leaves scope names out: a program
+    compiled without the scope is never served in its place."""
+    with jax.named_scope("numeric.search"):
+        slot = platform.searchsorted(key, pk).astype(jnp.int32)
+        miss = jnp.logical_or(~valid, jnp.take(
+            key, jnp.minimum(slot, out_cap - 1), mode="clip") != pk)
+        n_miss = jnp.sum(jnp.logical_and(valid, miss)).astype(jnp.int32)
+        return jnp.where(miss, out_cap, slot), n_miss
+
+
 @partial(jax.jit, static_argnames=("out_cap", "n_rows", "n_cols"))
 def _numeric_scatter(row: jax.Array, col: jax.Array, val: jax.Array,
                      key: jax.Array, nnz: jax.Array, *, out_cap: int,
@@ -374,22 +392,24 @@ def _numeric_scatter(row: jax.Array, col: jax.Array, val: jax.Array,
     absent from the structure (a stale structure used with
     ``validate=False``) lands there too, and its value is lost — so such
     misses poison ``Coo.ngroups`` past ``out_cap`` exactly like a backend
-    drop, never passing for a clean result."""
+    drop, never passing for a clean result. Each step runs under a
+    ``jax.named_scope`` (``numeric.key``, ``.search``, ``.scatter``,
+    ``.emit``): the names reach the ops' HLO metadata only, so a device
+    trace can charge every op to its step."""
     row, col, val = row.reshape(-1), col.reshape(-1), val.reshape(-1)
-    valid = jnp.logical_and(row >= 0, col >= 0)
-    pk = jnp.where(valid,
-                   row.astype(jnp.int32) * n_cols + col.astype(jnp.int32),
-                   0)
-    slot = platform.searchsorted(key, pk).astype(jnp.int32)
-    miss = jnp.logical_or(~valid, jnp.take(key, jnp.minimum(slot, out_cap - 1),
-                                           mode="clip") != pk)
-    slot = jnp.where(miss, out_cap, slot)
-    n_miss = jnp.sum(jnp.logical_and(valid, miss)).astype(jnp.int32)
-    sums = jax.ops.segment_sum(jnp.where(valid, val, 0), slot,
-                               num_segments=out_cap + 1)[:out_cap]
-    coo = _coo_from_slots(key, sums, nnz, out_cap=out_cap, n_rows=n_rows,
-                          n_cols=n_cols)
-    return _poison_overflow(coo, n_miss)
+    with jax.named_scope("numeric.key"):
+        valid = jnp.logical_and(row >= 0, col >= 0)
+        pk = jnp.where(valid,
+                       row.astype(jnp.int32) * n_cols + col.astype(jnp.int32),
+                       0)
+    slot, n_miss = _search_slots(key, pk, valid, out_cap=out_cap)
+    with jax.named_scope("numeric.scatter"):
+        sums = jax.ops.segment_sum(jnp.where(valid, val, 0), slot,
+                                   num_segments=out_cap + 1)[:out_cap]
+    with jax.named_scope("numeric.emit"):
+        coo = _coo_from_slots(key, sums, nnz, out_cap=out_cap,
+                              n_rows=n_rows, n_cols=n_cols)
+        return _poison_overflow(coo, n_miss)
 
 
 @partial(jax.jit, static_argnames=("out_cap", "n_rows", "n_cols", "group"))
@@ -415,12 +435,8 @@ def _numeric_stream(a_val, a_idx, b_val, b_idx, key, nnz, *, out_cap: int,
         c = jnp.broadcast_to(b_idx[None, :, :], (group, n, k_b)).reshape(-1)
         valid = jnp.logical_and(r >= 0, c >= 0)
         pk = jnp.where(valid, r * n_cols + c, 0).astype(jnp.int32)
-        slot = platform.searchsorted(key, pk).astype(jnp.int32)
-        miss = jnp.logical_or(
-            ~valid, jnp.take(key, jnp.minimum(slot, out_cap - 1),
-                             mode="clip") != pk)
-        slot = jnp.where(miss, out_cap, slot)
-        nm = nm + jnp.sum(jnp.logical_and(valid, miss)).astype(jnp.int32)
+        slot, miss = _search_slots(key, pk, valid, out_cap=out_cap)
+        nm = nm + miss
         acc = acc + jax.ops.segment_sum(jnp.where(valid, v, 0), slot,
                                         num_segments=out_cap + 1)
         return (acc, nm), ()
@@ -456,9 +472,13 @@ def spgemm_coo_numeric(a: EllRows, b: EllCols, structure, *,
     past ``out_cap`` — their values are lost, so ``overflowed()`` flags it
     and ``check=True`` raises instead of returning silently-wrong output.
     ``check=True`` otherwise runs the usual overflow check for API parity
-    (a correctly built structure cannot overflow or miss)."""
+    (a correctly built structure cannot overflow or miss).
+
+    Instrumented (repro.obs): ``spgemm.validate`` around the fingerprint
+    check, then ``spgemm.numeric`` with ``spgemm.multiply`` inside it."""
     if validate:
-        structure.validate(a, b)
+        with _obs.span("spgemm.validate"):
+            structure.validate(a, b)
     if a.val.ndim != 2:
         raise ValueError("batched operands: use spgemm_coo_numeric_batched "
                          "with a structure from make_structure_batched")
@@ -475,7 +495,9 @@ def spgemm_coo_numeric(a: EllRows, b: EllCols, structure, *,
                                   out_cap=st.out_cap, n_rows=st.n_rows,
                                   n_cols=st.n_cols, group=grp)
         else:
-            val, row, col = sccp_multiply(a, b)
+            with _obs.span("spgemm.multiply", backend=backend, k_a=a.k,
+                           k_b=b.k, n=a.n_cols):
+                val, row, col = _obs.sync(sccp_multiply(a, b))
             coo = _numeric_scatter(row, col, val, st.key, st.nnz,
                                    out_cap=st.out_cap, n_rows=st.n_rows,
                                    n_cols=st.n_cols)

@@ -1,9 +1,9 @@
-"""repro.obs — zero-dependency tracing + metrics for the SpGEMM stack.
+"""repro.obs — tracing + metrics for the SpGEMM stack.
 
 Disabled by default and free when disabled; ``repro.obs.enable()`` turns on
-span recording (trace.py), counters/planner-evidence (metrics.py), and the
-roofline join (roofline.py, imported lazily to keep ``repro.core`` import
-order acyclic).
+span recording (trace.py, mirrored into the JAX profiler's trace) and
+counters/planner-evidence (metrics.py), including the backend-compile
+counter.
 
     import repro.obs as obs
     obs.enable()
@@ -16,8 +16,8 @@ from __future__ import annotations
 from typing import Any, Dict
 
 from . import metrics, trace
-from .trace import (NULL_SPAN, Span, Tracer, export_chrome, get_tracer,
-                    instant, is_enabled, span, sync)
+from .trace import (NULL_SPAN, Span, Tracer, call, export_chrome,
+                    get_tracer, instant, is_enabled, span, sync)
 
 
 def enable(reset: bool = False) -> None:
@@ -26,6 +26,7 @@ def enable(reset: bool = False) -> None:
         trace.reset()
         metrics.reset()
     trace.enable()
+    metrics.watch_compiles()
 
 
 def disable() -> None:
@@ -43,15 +44,8 @@ def snapshot() -> Dict[str, Any]:
             "metrics": metrics.snapshot()}
 
 
-def __getattr__(name: str):
-    if name == "roofline":          # lazy: roofline imports repro.core
-        import importlib
-        return importlib.import_module(".roofline", __name__)
-    raise AttributeError(f"module 'repro.obs' has no attribute {name!r}")
-
-
 __all__ = [
     "trace", "metrics", "enable", "disable", "reset", "snapshot",
-    "span", "sync", "instant", "is_enabled", "export_chrome",
+    "span", "call", "sync", "instant", "is_enabled", "export_chrome",
     "get_tracer", "Span", "Tracer", "NULL_SPAN",
 ]

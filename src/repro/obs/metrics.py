@@ -16,6 +16,10 @@ stays empty. Enabled, the library forwards:
   exactly once per offending call).
 - **per-schedule modeled comm bytes** from ``core/distributed.py``.
 - **serve-engine** per-request queue/compute latency and batch occupancy.
+- **backend compiles**: one ``jax.monitoring`` duration listener
+  (:func:`watch_compiles`, registered by ``repro.obs.enable``) counts
+  ``jax.compiles``, sums their seconds in ``jax.compile_s`` and records a
+  ``jax.compile`` instant inside the span that triggered the compile.
 
 Histograms are streaming (count/total/min/max) — no samples retained.
 """
@@ -164,6 +168,32 @@ def record_backend_us(key: str, backend: str, us: float) -> None:
 
 def snapshot() -> Dict[str, Any]:
     return _metrics.snapshot()
+
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_watch_lock = threading.Lock()
+_watching = False          # jax.monitoring's listeners are process-wide
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    if not _trace.is_enabled() or event != COMPILE_EVENT:
+        return
+    inc("jax.compiles")
+    inc("jax.compile_s", secs)
+    _trace.instant("jax.compile", fun=kw.get("fun_name"), seconds=secs)
+
+
+def watch_compiles() -> None:
+    """Register the compile listener (once per process) and seed its
+    counters, so a stretch without compiles reads 0 rather than nothing."""
+    global _watching
+    with _watch_lock:
+        if not _watching:
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            _watching = True
+    inc("jax.compiles", 0.0)
+    inc("jax.compile_s", 0.0)
 
 
 def reset() -> None:

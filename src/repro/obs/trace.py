@@ -1,4 +1,4 @@
-"""Zero-dependency runtime tracing for the SpGEMM stack.
+"""Runtime tracing for the SpGEMM stack (jax is imported only once enabled).
 
 One global :class:`Tracer`, **disabled by default**: every instrumentation
 point in the library goes through :func:`span` / :func:`instant` /
@@ -16,7 +16,15 @@ the span closes — so a span measures compute, not jit dispatch. Under
 ``jax.jit`` the instrumentation runs once at trace time (spans are tagged
 ``traced=True`` and never block on tracers); real per-phase numbers come
 from calling the instrumented entry points outside jit, or from jitting the
-phases separately (obs/roofline.py does exactly that).
+phases separately.
+
+Every recorded event carries an ``id``, the ``parent_id`` of the span it
+ran inside, and a ``call_id``: the id of the enclosing :func:`call` span
+(``spgemm.call`` at the ``repro.spgemm`` front door), shared by every span
+of one library call. An enabled span also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so while a JAX profiler
+trace runs each span is a host event in the same ``.xplane.pb`` as the
+device ops, on the profiler's clock.
 
 Span args are sanitized: numbers/strings/bools pass through, arrays are
 reduced to ``dtype+shape`` strings — **matrix values never enter a trace**
@@ -25,11 +33,12 @@ reduced to ``dtype+shape`` strings — **matrix values never enter a trace**
 Export: :meth:`Tracer.export_chrome` emits Chrome-trace/Perfetto JSON
 (``traceEvents`` with ``ph='X'`` complete events, µs timestamps);
 :meth:`Tracer.snapshot` returns the raw span dicts for programmatic joins
-(obs/metrics.py and obs/roofline.py consume it).
+(obs/metrics.py consumes it).
 """
 from __future__ import annotations
 
 import contextvars
+import itertools
 import json
 import threading
 import time
@@ -39,6 +48,7 @@ MAX_EVENTS = 200_000     # hard buffer bound; beyond it events are counted, not 
 
 _stack: "contextvars.ContextVar[tuple]" = contextvars.ContextVar(
     "repro_obs_span_stack", default=())
+_ids = itertools.count(1)   # event ids; next() is atomic under the GIL
 
 
 def _clean_args(args: Dict[str, Any]) -> Dict[str, Any]:
@@ -85,20 +95,23 @@ NULL_SPAN = _NullSpan()
 
 class Span:
     """One live span. Use as a context manager; ``dur_us`` is readable after
-    exit (obs/roofline.py times measurements through it)."""
+    exit (callers feed it to the metrics registry)."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "dur_us", "_token",
-                 "parent", "depth", "traced")
+    __slots__ = ("tracer", "name", "args", "t0", "dur_us", "_token", "_ann",
+                 "id", "parent_id", "call_id", "new_call", "depth", "traced")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any],
-                 traced: bool):
+                 traced: bool, new_call: bool = False):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.traced = traced
+        self.new_call = new_call
         self.t0 = 0
         self.dur_us: Optional[float] = None
-        self.parent: Optional[str] = None
+        self.id = next(_ids)
+        self.parent_id: Optional[int] = None
+        self.call_id: Optional[int] = None
         self.depth = 0
 
     def set(self, **kw) -> "Span":
@@ -107,15 +120,22 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        from jax.profiler import TraceAnnotation
         stack = _stack.get()
-        self.parent = stack[-1].name if stack else None
+        parent = stack[-1] if stack else None
+        self.parent_id = parent.id if parent else None
+        self.call_id = (self.id if self.new_call
+                        else parent.call_id if parent else None)
         self.depth = len(stack)
         self._token = _stack.set(stack + (self,))
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
         _stack.reset(self._token)
         self.dur_us = (t1 - self.t0) / 1e3
         self.tracer._record(self, t1)
@@ -154,28 +174,24 @@ class Tracer:
 
     # ------------------------------------------------------------ recording
 
-    def span(self, name: str, **args) -> Span:
+    def span(self, name: str, new_call: bool = False, **args) -> Span:
         traced = bool(args.pop("traced", False)) or _under_jit()
-        return Span(self, name, _clean_args(args), traced)
+        return Span(self, name, _clean_args(args), traced, new_call)
 
     def instant(self, name: str, **args) -> None:
-        """Record a point event (chrome ``ph='i'``)."""
+        """Record a point event (chrome ``ph='i'``) in the current span."""
         if not self._enabled:
             return
         now = time.perf_counter_ns()
-        ev = {"name": name, "ph": "i",
-              "ts_us": (now - self._epoch_ns) / 1e3, "dur_us": 0.0,
-              "tid": threading.get_ident() & 0xFFFF,
-              "depth": len(_stack.get()), "parent": None,
-              "args": _clean_args(args)}
         stack = _stack.get()
-        if stack:
-            ev["parent"] = stack[-1].name
-        with self._lock:
-            if len(self._events) < MAX_EVENTS:
-                self._events.append(ev)
-            else:
-                self._dropped += 1
+        parent = stack[-1] if stack else None
+        self._append({"name": name, "ph": "i",
+                      "ts_us": (now - self._epoch_ns) / 1e3, "dur_us": 0.0,
+                      "tid": threading.get_ident() & 0xFFFF,
+                      "depth": len(stack), "id": next(_ids),
+                      "parent_id": parent.id if parent else None,
+                      "call_id": parent.call_id if parent else None,
+                      "args": _clean_args(args)})
 
     def _record(self, sp: Span, t1_ns: int) -> None:
         if not self._enabled:
@@ -183,11 +199,15 @@ class Tracer:
         args = sp.args
         if sp.traced:
             args = dict(args, traced=True)
-        ev = {"name": sp.name, "ph": "X",
-              "ts_us": (sp.t0 - self._epoch_ns) / 1e3,
-              "dur_us": (t1_ns - sp.t0) / 1e3,
-              "tid": threading.get_ident() & 0xFFFF,
-              "depth": sp.depth, "parent": sp.parent, "args": args}
+        self._append({"name": sp.name, "ph": "X",
+                      "ts_us": (sp.t0 - self._epoch_ns) / 1e3,
+                      "dur_us": (t1_ns - sp.t0) / 1e3,
+                      "tid": threading.get_ident() & 0xFFFF,
+                      "depth": sp.depth, "id": sp.id,
+                      "parent_id": sp.parent_id, "call_id": sp.call_id,
+                      "args": args})
+
+    def _append(self, ev: Dict[str, Any]) -> None:
         with self._lock:
             if len(self._events) < MAX_EVENTS:
                 self._events.append(ev)
@@ -257,12 +277,10 @@ def reset() -> None:
 
 
 def _under_jit() -> bool:
-    """True while jax is tracing (spans then measure trace time, flagged)."""
-    try:
-        import jax
-        return isinstance(jax.numpy.zeros(()) + 0, jax.core.Tracer)
-    except Exception:
-        return False
+    """True while jax is tracing (jit, vmap, grad: spans then measure trace
+    time, flagged). Reads JAX's trace state and dispatches nothing."""
+    import jax
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def span(name: str, **args):
@@ -271,6 +289,14 @@ def span(name: str, **args):
     if not _tracer._enabled:
         return NULL_SPAN
     return _tracer.span(name, **args)
+
+
+def call(name: str, **args):
+    """A span that starts one library call: it and every event inside it
+    carry its id as ``call_id``. Disabled: the shared null span."""
+    if not _tracer._enabled:
+        return NULL_SPAN
+    return _tracer.span(name, new_call=True, **args)
 
 
 def instant(name: str, **args) -> None:

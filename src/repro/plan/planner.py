@@ -254,7 +254,20 @@ def make_plan(a: EllRows, b: EllCols, *, out_cap: Optional[int] = None,
     op-count winner would materialize more, the cheapest backend within the
     budget is chosen instead — ``'stream'`` (whose intermediate is O(n·k_b),
     not O(k_a·n·k_b)) when no materializing backend fits.
+
+    Instrumented (repro.obs): the whole of it is one ``spgemm.plan`` span,
+    with ``spgemm.symbolic`` as its child.
     """
+    with _obs.span("spgemm.plan", backend=backend or "auto",
+                   n_rows=a.n_rows, n_cols=b.n_cols):
+        return _make_plan(a, b, out_cap=out_cap, backend=backend,
+                          exact=exact, tile=tile, slack=slack,
+                          mem_budget=mem_budget)
+
+
+def _make_plan(a: EllRows, b: EllCols, *, out_cap: Optional[int],
+               backend: Optional[str], exact: bool, tile: int, slack: float,
+               mem_budget: Optional[int]) -> Plan:
     if mem_budget is None:
         mem_budget = default_mem_budget()
     if backend is not None and backend not in BACKENDS:

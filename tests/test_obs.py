@@ -1,6 +1,7 @@
 """Observability tests: the disabled-overhead contract, span nesting (jit,
-threads), Chrome-trace export, metrics stability, exactly-once poison /
-overflow events, cache-stats snapshots and the roofline join."""
+threads), call ids, the profiler mirror, the compile counter, Chrome-trace
+export, metrics stability, exactly-once poison / overflow events and
+cache-stats snapshots."""
 import json
 import threading
 import time
@@ -100,9 +101,9 @@ def test_enabled_spans_nest():
             pass
     evs = tr.get_tracer().spans()
     by_name = {e["name"]: e for e in evs}
-    assert by_name["inner"]["parent"] == "outer"
+    assert by_name["inner"]["parent_id"] == by_name["outer"]["id"]
     assert by_name["inner"]["depth"] == 1
-    assert by_name["outer"]["parent"] is None
+    assert by_name["outer"]["parent_id"] is None
     assert by_name["outer"]["depth"] == 0
     # child interval inside parent interval
     o, i = by_name["outer"], by_name["inner"]
@@ -112,22 +113,26 @@ def test_enabled_spans_nest():
 
 def test_spans_nest_across_threads():
     obs.enable(reset=True)
+    # both threads sit inside their spans at once: the spans interleave,
+    # and neither thread can exit and hand its ident to the other
+    both_inside = threading.Barrier(2, timeout=10)
 
     def work(tag):
         with tr.span(f"outer-{tag}"):
             with tr.span(f"inner-{tag}"):
-                time.sleep(0.002)
+                both_inside.wait()
 
     ts = [threading.Thread(target=work, args=(i,)) for i in range(2)]
     for t in ts:
         t.start()
     for t in ts:
-        t.join()
+        t.join(timeout=10)
+        assert not t.is_alive()
     evs = tr.get_tracer().spans()
     for i in range(2):
         inner = next(e for e in evs if e["name"] == f"inner-{i}")
         outer = next(e for e in evs if e["name"] == f"outer-{i}")
-        assert inner["parent"] == f"outer-{i}"      # never the other thread's
+        assert inner["parent_id"] == outer["id"]    # never the other thread's
         assert inner["depth"] == 1 and outer["depth"] == 0
         assert inner["tid"] == outer["tid"]
     tids = {e["tid"] for e in evs}
@@ -149,6 +154,136 @@ def test_spans_under_jit_are_flagged_and_fire_once():
     assert len(tr.get_tracer().spans()) == len(evs1)
     # span stack balanced after tracing
     assert tr._stack.get() == ()
+
+
+def test_enabled_span_outside_jit_runs_no_device_op(tmp_path):
+    """Deciding whether a span runs under jit reads JAX's trace state: an
+    enabled span outside jit executes no XLA program (the CPU profiler
+    records one ``PjRtCpuExecutable::Execute`` per program run)."""
+    from jax.profiler import ProfileData
+
+    def executed(fn):
+        path = tmp_path / fn.__name__
+        jax.profiler.start_trace(str(path))
+        fn()
+        jax.profiler.stop_trace()
+        (pb,) = path.glob("**/*.xplane.pb")
+        names = [e.name for pl in ProfileData.from_file(str(pb)).planes
+                 for ln in pl.lines for e in ln.events]
+        assert "probe" in names                   # the span's own annotation
+        return sum(n.startswith("PjRtCpuExecutable::Execute") for n in names)
+
+    x = jnp.ones(3)
+    jax.block_until_ready(x + 1)                  # compiled before tracing
+    obs.enable(reset=True)
+
+    def span_only():
+        with tr.span("probe", n=1):
+            pass
+
+    def span_and_op():
+        with tr.span("probe"):
+            jax.block_until_ready(x + 1)
+
+    assert executed(span_only) == 0
+    assert executed(span_and_op) >= 1             # the detector sees ops
+
+
+def test_span_under_jit_flagged_without_device_work():
+    obs.enable(reset=True)
+
+    def f(x):
+        with tr.span("inside"):
+            return x * 2
+
+    jax.block_until_ready(jax.jit(f)(jnp.ones(5)))
+    with tr.span("outside"):
+        pass
+    by_name = {e["name"]: e for e in tr.get_tracer().spans()}
+    assert by_name["inside"]["args"].get("traced") is True
+    assert "traced" not in by_name["outside"]["args"]
+
+
+def test_spgemm_spans_share_call_id_and_link_parents():
+    """Every span of one repro.spgemm call carries the id of its
+    ``spgemm.call`` root as ``call_id``; ``parent_id`` gives the nesting:
+    plan ⊃ symbolic, accumulate ⊃ sort, merge (cold); validate beside
+    numeric ⊃ multiply (warm)."""
+    import repro
+    a, b = _operands()
+    st = repro.make_structure(a, b)
+    obs.enable(reset=True)
+    repro.spgemm(a, b, out_cap="auto", accumulator="sort", check=True)
+    repro.spgemm(a, b, structure=st)
+    evs = tr.get_tracer().spans()
+    roots = [e for e in evs if e["name"] == "spgemm.call"]
+    assert len(roots) == 2
+    cold, warm = sorted(roots, key=lambda e: e["ts_us"])
+    for root in roots:
+        assert root["parent_id"] is None and root["call_id"] == root["id"]
+        assert root["args"]["lanes"] == a.k * a.n_cols * b.k
+        assert root["args"]["nnz"] > 0
+    by_id = {e["id"]: e for e in evs}
+
+    def parent(name, call):
+        (e,) = [e for e in evs if e["name"] == name
+                and e["call_id"] == call["id"]]
+        return by_id[e["parent_id"]]["name"]
+
+    assert parent("spgemm.plan", cold) == "spgemm.call"
+    assert parent("spgemm.symbolic", cold) == "spgemm.plan"
+    assert parent("spgemm.multiply", cold) == "spgemm.call"
+    assert parent("spgemm.accumulate", cold) == "spgemm.call"
+    assert parent("spgemm.accumulate.sort", cold) == "spgemm.accumulate"
+    assert parent("spgemm.accumulate.merge", cold) == "spgemm.accumulate"
+    assert parent("spgemm.validate", warm) == "spgemm.call"
+    assert parent("spgemm.numeric", warm) == "spgemm.call"
+    assert parent("spgemm.multiply", warm) == "spgemm.numeric"
+    assert all(e["call_id"] in (cold["id"], warm["id"]) for e in evs)
+
+
+def test_disabled_front_door_records_and_annotates_nothing(monkeypatch):
+    """Tracing off, ``repro.spgemm`` takes the bare route: the root span is
+    the shared null span and no profiler annotation is opened."""
+    import jax.profiler
+    import repro
+
+    def refuse(*_a, **_k):
+        raise AssertionError("annotation opened while tracing is off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    assert tr.call("spgemm.call", lanes=1) is tr.NULL_SPAN
+    a, b = _operands()
+    repro.spgemm(a, b, out_cap="auto", accumulator="sort")
+    assert obs.snapshot()["trace"]["events"] == []
+
+
+def test_compile_listener_counts_fresh_compile_and_names_parent():
+    x = jnp.ones(11)
+    obs.enable(reset=True)
+    obs.disable()
+    f = jax.jit(lambda v: v * 3.0 - 1.0)
+    jax.block_until_ready(f(x))                   # disabled: not counted
+    assert mt.snapshot()["counters"]["jax.compiles"] == 0
+    obs.enable()
+    g = jax.jit(lambda v: v * 5.0 + 2.0)
+    with tr.span("step") as sp:
+        jax.block_until_ready(g(x))
+        jax.block_until_ready(g(x))               # cached: no second compile
+    counters = mt.snapshot()["counters"]
+    assert counters["jax.compiles"] == 1
+    assert counters["jax.compile_s"] > 0
+    (ev,) = [e for e in tr.get_tracer().snapshot()["events"]
+             if e["name"] == "jax.compile"]
+    assert ev["parent_id"] == sp.id and ev["ph"] == "i"
+    assert ev["args"]["seconds"] > 0
+
+
+def test_enable_seeds_compile_counter_at_zero():
+    obs.enable(reset=True)
+    assert mt.snapshot()["counters"]["jax.compiles"] == 0
+    obs.reset()
+    assert mt.snapshot()["counters"] == {}
 
 
 # ------------------------------------------------------------------ export
@@ -212,8 +347,13 @@ def test_metrics_snapshot_stable_across_identical_runs():
         obs.reset()
         return snap
 
+    def program_counters(snap):
+        # compile counts follow the jit cache, which the first run fills
+        return {k: v for k, v in snap["counters"].items()
+                if not k.startswith("jax.compile")}
+
     s1, s2 = run(), run()
-    assert s1["counters"] == s2["counters"]
+    assert program_counters(s1) == program_counters(s2)
     assert set(s1["planner"]) == set(s2["planner"])
     for k in s1["planner"]:
         assert s1["planner"][k]["backend"] == s2["planner"][k]["backend"]
@@ -314,17 +454,3 @@ def test_engine_stats_dict_and_callable():
     assert snap["queue_s_per_request"] >= 0.0
     assert snap["compute_s_per_request"] > 0.0
     assert "hits" in snap["structure_cache"]
-
-
-# ---------------------------------------------------------------- roofline
-
-
-def test_roofline_fractions_in_gate_range():
-    from repro.obs import roofline as rl
-    a, b = _operands()
-    res = rl.measure_roofline(a, b, backends=("sort", "stream"), iters=1)
-    assert set(res) == {"sort", "stream"}
-    for r in res.values():
-        assert 0.0 < r["frac"] <= 1.5
-        assert r["modeled_bytes"] > 0 and r["us"] > 0
-    assert not obs.is_enabled()                     # tracer state restored
