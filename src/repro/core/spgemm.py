@@ -381,35 +381,88 @@ def _search_slots(key: jax.Array, pk: jax.Array, valid: jax.Array, *,
         return jnp.where(miss, out_cap, slot), n_miss
 
 
-@partial(jax.jit, static_argnames=("out_cap", "n_rows", "n_cols"))
-def _numeric_scatter(row: jax.Array, col: jax.Array, val: jax.Array,
-                     key: jax.Array, nnz: jax.Array, *, out_cap: int,
-                     n_rows: int, n_cols: int) -> Coo:
-    """Numeric-phase core: binary-search each product's packed key into the
-    precomputed sorted unique keys, one segment-sum into the slots. No
-    planning, no coordinate sort — O(p log u) search + O(p) sum. Invalid
-    lanes land in the discarded dump slot; a VALID product whose key is
-    absent from the structure (a stale structure used with
-    ``validate=False``) lands there too, and its value is lost — so such
-    misses poison ``Coo.ngroups`` past ``out_cap`` exactly like a backend
-    drop, never passing for a clean result. Each step runs under a
-    ``jax.named_scope`` (``numeric.key``, ``.search``, ``.scatter``,
-    ``.emit``): the names reach the ops' HLO metadata only, so a device
-    trace can charge every op to its step."""
-    row, col, val = row.reshape(-1), col.reshape(-1), val.reshape(-1)
+def _lanes(a_idx: jax.Array, b_idx: jax.Array):
+    """Flat ``(row, col)`` of every product lane of the index planes
+    ``a_idx`` (k_a, n) and ``b_idx`` (n, k_b), in ``sccp_multiply``'s
+    ``(k_a, n, k_b)`` lane order (-1 where a plane slot is empty)."""
+    k_a, n = a_idx.shape
+    k_b = b_idx.shape[1]
+    return (jnp.broadcast_to(a_idx[:, :, None], (k_a, n, k_b)).reshape(-1),
+            jnp.broadcast_to(b_idx[None, :, :], (k_a, n, k_b)).reshape(-1))
+
+
+def _find_slots(row, col, key, *, n_cols: int, out_cap: int):
+    """``_search_slots`` of the flat product lanes ``(row, col)``."""
     with jax.named_scope("numeric.key"):
         valid = jnp.logical_and(row >= 0, col >= 0)
         pk = jnp.where(valid,
                        row.astype(jnp.int32) * n_cols + col.astype(jnp.int32),
                        0)
-    slot, n_miss = _search_slots(key, pk, valid, out_cap=out_cap)
+    return _search_slots(key, pk, valid, out_cap=out_cap)
+
+
+@partial(jax.jit, static_argnames=("n_cols", "out_cap"))
+def lane_slots(a_idx: jax.Array, b_idx: jax.Array, key: jax.Array, *,
+               n_cols: int, out_cap: int) -> jax.Array:
+    """Output slot of every product lane of the index planes among the
+    sorted unique keys ``key``, flat in ``sccp_multiply``'s lane order: the
+    search a numeric call without cached slots makes, made once for a
+    structure (``plan.structure``). Invalid lanes and absent keys get
+    ``out_cap``."""
+    return _find_slots(*_lanes(a_idx, b_idx), key, n_cols=n_cols,
+                       out_cap=out_cap)[0]
+
+
+@partial(jax.jit, static_argnames=("out_cap", "n_rows", "n_cols"))
+def _numeric_scatter(val: jax.Array, row, col, key: jax.Array,
+                     nnz: jax.Array, cached=None, *, out_cap: int,
+                     n_rows: int, n_cols: int):
+    """Numeric-phase core: the output slot of each product of
+    ``sccp_multiply``'s ``(val, row, col)``, then one segment-sum into the
+    slots. No planning, no coordinate sort. Returns the ``Coo`` and
+    whether the cached slots were taken.
+
+    Without ``cached``, each product's packed key is binary-searched in the
+    sorted unique keys, O(p log u). ``cached`` is ``(slot, a_idx, b_idx,
+    a_seen, b_seen)``: a structure's per-lane slots (as long as ``val``)
+    and the planes it found them from, with the operands' index planes,
+    which stand for ``row`` and ``col`` (then ``None``); where the
+    operands' planes equal the structure's on the device the slots are
+    ``slot``, else the search runs on the planes' lanes. Invalid lanes land
+    in the discarded dump slot; a VALID product whose key is absent from
+    the structure (a stale structure used with ``validate=False``) lands
+    there too, and its value is lost — so such misses poison
+    ``Coo.ngroups`` past ``out_cap`` exactly like a backend drop, never
+    passing for a clean result. Each step runs under a ``jax.named_scope``
+    (``numeric.key``, ``.search`` — the plane compare and the choice
+    included —, ``.scatter``, ``.emit``): the names reach the ops' HLO
+    metadata only, so a device trace can charge every op to its step."""
+    val = val.reshape(-1)
+    if cached is None:
+        row, col = row.reshape(-1), col.reshape(-1)
+        slot, n_miss = _find_slots(row, col, key, n_cols=n_cols,
+                                   out_cap=out_cap)
+        hit = jnp.bool_(False)
+    else:
+        slot, a_idx, b_idx, a_seen, b_seen = cached
+        with jax.named_scope("numeric.search"):
+            hit = jnp.logical_and(jnp.array_equal(a_idx, a_seen),
+                                  jnp.array_equal(b_idx, b_seen))
+            # the lanes are built inside the branch: as operands of the
+            # cond they would be materialized on every call
+            slot, n_miss = jax.lax.cond(
+                hit, lambda: (slot, jnp.int32(0)),
+                lambda: _find_slots(*_lanes(a_idx, b_idx), key,
+                                    n_cols=n_cols, out_cap=out_cap))
+        row, col = _lanes(a_idx, b_idx)
     with jax.named_scope("numeric.scatter"):
+        valid = jnp.logical_and(row >= 0, col >= 0)
         sums = jax.ops.segment_sum(jnp.where(valid, val, 0), slot,
                                    num_segments=out_cap + 1)[:out_cap]
     with jax.named_scope("numeric.emit"):
         coo = _coo_from_slots(key, sums, nnz, out_cap=out_cap,
                               n_rows=n_rows, n_cols=n_cols)
-        return _poison_overflow(coo, n_miss)
+        return _poison_overflow(coo, n_miss), hit
 
 
 @partial(jax.jit, static_argnames=("out_cap", "n_rows", "n_cols", "group"))
@@ -463,19 +516,28 @@ def spgemm_coo_numeric(a: EllRows, b: EllCols, structure, *,
     slot segment-sum fixes one canonical order; backends differ only in
     rounding). Repeat calls with the same shapes hit XLA's compile cache —
     the intended serving pattern: one symbolic call, thousands of numeric
-    calls. Structures from stream-backed plans scan A slab groups so the
-    product stream is never materialized (same memory contract as the cold
-    stream path). ``validate=False`` skips the fingerprint check (e.g. under
-    jit, or deliberate reuse across value-only updates — which is exactly
-    what the fingerprint permits anyway); a stale structure then routes
-    unknown keys to the discarded overflow slot AND poisons ``Coo.ngroups``
-    past ``out_cap`` — their values are lost, so ``overflowed()`` flags it
-    and ``check=True`` raises instead of returning silently-wrong output.
+    calls. A structure from ``make_structure`` holds each product lane's
+    output slot (``slot``, 4 B per lane, found once at build time for every
+    backend but ``'stream'``) with the index planes it was found from: the
+    call compares the operands' planes with those on the device and, where
+    they are equal, sums into the cached slots; otherwise — or where the
+    structure has no slots, or slots for another lane count — it searches
+    every product's key among the structure's keys. Structures from
+    stream-backed plans scan A slab groups so the product stream is never
+    materialized (same memory contract as the cold stream path).
+    ``validate=False`` skips the fingerprint check (e.g. under jit, or
+    deliberate reuse across value-only updates — which is exactly what the
+    fingerprint permits anyway); a stale structure then routes unknown
+    keys to the discarded overflow slot AND poisons ``Coo.ngroups`` past
+    ``out_cap`` — their values are lost, so ``overflowed()`` flags it and
+    ``check=True`` raises instead of returning silently-wrong output.
     ``check=True`` otherwise runs the usual overflow check for API parity
     (a correctly built structure cannot overflow or miss).
 
     Instrumented (repro.obs): ``spgemm.validate`` around the fingerprint
-    check, then ``spgemm.numeric`` with ``spgemm.multiply`` inside it."""
+    check, then ``spgemm.numeric`` with ``spgemm.multiply`` inside it; each
+    call counts ``spgemm.numeric.slot_hits`` where it took the cached
+    slots, else ``spgemm.numeric.slot_searches``."""
     if validate:
         with _obs.span("spgemm.validate"):
             structure.validate(a, b)
@@ -494,16 +556,24 @@ def spgemm_coo_numeric(a: EllRows, b: EllCols, structure, *,
             coo = _numeric_stream(a.val, a.idx, b.val, b.idx, st.key, st.nnz,
                                   out_cap=st.out_cap, n_rows=st.n_rows,
                                   n_cols=st.n_cols, group=grp)
+            hit = False
         else:
             with _obs.span("spgemm.multiply", backend=backend, k_a=a.k,
                            k_b=b.k, n=a.n_cols):
                 val, row, col = _obs.sync(sccp_multiply(a, b))
-            coo = _numeric_scatter(row, col, val, st.key, st.nnz,
-                                   out_cap=st.out_cap, n_rows=st.n_rows,
-                                   n_cols=st.n_cols)
+            cached = None
+            if st.slot is not None and st.slot.shape == (val.size,):
+                cached = (st.slot, a.idx, b.idx, st.a_idx, st.b_idx)
+                row = col = None
+            coo, hit = _numeric_scatter(val, row, col, st.key, st.nnz,
+                                        cached, out_cap=st.out_cap,
+                                        n_rows=st.n_rows, n_cols=st.n_cols)
         _obs.sync(coo.val)
         if _obs.is_enabled() and not isinstance(coo.ngroups, jax.core.Tracer):
-            ng = int(coo.ngroups)
+            ng, hit = jax.device_get((coo.ngroups, hit))
+            ng = int(ng)
+            _obs_metrics.inc("spgemm.numeric.slot_hits" if hit
+                             else "spgemm.numeric.slot_searches")
             sp.set(nnz=ng)
             if ng > st.out_cap:
                 # structure-miss drop → _poison_overflow stamped ngroups
@@ -536,8 +606,8 @@ def spgemm_coo_numeric_batched(a: EllRows, b: EllCols, structure, *,
 
     def one(a_i, b_i, key, nnz):
         val, row, col = sccp_multiply(a_i, b_i)
-        return _numeric_scatter(row, col, val, key, nnz, out_cap=st.out_cap,
-                                n_rows=st.n_rows, n_cols=st.n_cols)
+        return _numeric_scatter(val, row, col, key, nnz, out_cap=st.out_cap,
+                                n_rows=st.n_rows, n_cols=st.n_cols)[0]
 
     coo = jax.vmap(one)(a, b, st.key, st.nnz)
     if check:

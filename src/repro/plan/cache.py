@@ -14,13 +14,20 @@ Three optional layers on top of the LRU:
     carrying the Plan/DistPlan statics), so a fresh process — or a fleet of
     them sharing a filesystem — warm-starts without re-running the symbolic
     phase. Writes are atomic (tmp + rename); a corrupt or stale file is
-    treated as a miss, never an error.
+    treated as a miss, never an error. The file holds no per-lane slots:
+    a structure read from it finds them again from the operands, as
+    ``make_structure`` does.
   * **Measured autotune** (``autotune=True``): on first build the planner's
     cost-model backend choice is validated against short timed probes of
     every candidate backend on the real operands; the measured winner's plan
     is cached (probe timings recorded in ``plan.est['autotune_us']``).
   * **Stats** (:meth:`StructureCache.stats`): hit / miss / eviction /
     disk-hit / autotune counters for capacity planning and tests.
+
+Memory: a cached structure that is not stream-planned holds each product
+lane's output slot, 4 B per product lane (0.925 GB at bcsstk32's
+2.31·10⁸ lanes), besides its output coordinates, and keeps the operands'
+index planes it was built from alive.
 
 Thread-safe: lookups and LRU mutation hold an internal lock; the expensive
 build runs outside it (concurrent first calls on the same pattern may both
@@ -44,7 +51,8 @@ from repro.obs import metrics as _obs_metrics
 from repro.obs import trace as _obs
 
 from .planner import BACKENDS, DistPlan, Plan
-from .structure import SpgemmStructure, fingerprint, make_structure
+from .structure import (SpgemmStructure, fingerprint, make_structure,
+                        with_slots)
 
 _FORMAT_VERSION = 1
 
@@ -132,6 +140,7 @@ class StructureCache:
         if self.cache_dir is not None:
             st = self._load_disk(fp)
             if st is not None:
+                st = with_slots(st, a, b)
                 with self._lock:
                     self._stats["disk_hits"] += 1
                 _obs_metrics.inc("structure_cache.disk_hits")

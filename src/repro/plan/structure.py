@@ -20,8 +20,9 @@ Contents of a structure:
 
   * ``key``      — the sorted unique packed output coordinates of C
                    (``row·n_cols + col``), padded to ``out_cap`` with
-                   ``KEY_INVALID``: the numeric phase maps every product to
-                   its output slot by one ``searchsorted`` against this.
+                   ``KEY_INVALID``: a numeric call without ``slot`` maps
+                   every product to its output slot by one
+                   ``searchsorted`` against this.
   * ``row_nnz``  — per-row unique-coordinate counts of C.
   * ``seg``      — row segment boundaries (exclusive prefix sum of
                    ``row_nnz``), CSR-style ``indptr`` of the output.
@@ -34,6 +35,23 @@ Contents of a structure:
                    distributed path reuses planning per schedule too — the
                    warm numeric path also reads the cached pick (and its
                    ``pr × pc`` grid) to choose its rotation schedule.
+  * ``slot``     — each product lane's output slot, int32 of shape
+                   ``(k_a·n·k_b,)`` in the lane order of ``sccp_multiply``
+                   (``(k_a, n, k_b)`` flattened); invalid lanes hold the
+                   dump slot ``out_cap``. ``make_structure`` finds it once,
+                   with the numeric phase's own search, for every backend
+                   but ``'stream'`` (whose plans exist so that no
+                   per-lane plane is ever held): 4 B per product lane,
+                   0.925 GB at bcsstk32's 2.31·10⁸ lanes.
+  * ``a_idx``, ``b_idx`` — the index planes ``slot`` was found from. The
+                   numeric phase compares the operands' planes with them
+                   on the device and takes ``slot`` only where they are
+                   equal; otherwise it searches ``key`` as without
+                   ``slot``, so a stale structure used with
+                   ``validate=False`` still poisons ``ngroups``.
+
+``slot``, ``a_idx`` and ``b_idx`` are ``None`` on batched structures, on
+stream-planned ones and on any built by the constructor without them.
 
 Packed int32 keys require ``n_rows·n_cols < 2³¹`` — the same structural
 precondition every packed-key backend carries; larger coordinate spaces stay
@@ -107,15 +125,21 @@ class SpgemmStructure:
     fp: Optional[str]
     plan: Plan
     dist_plans: Tuple[Tuple[str, DistPlan], ...] = ()
+    slot: Optional[jax.Array] = None    # (k_a·n·k_b,) int32 lane → slot
+    a_idx: Optional[jax.Array] = None   # (k_a, n) planes slot was found from
+    b_idx: Optional[jax.Array] = None   # (n, k_b)
 
     def tree_flatten(self):
-        return ((self.key, self.row_nnz, self.seg, self.nnz),
+        return ((self.key, self.row_nnz, self.seg, self.nnz, self.slot,
+                 self.a_idx, self.b_idx),
                 (self.n_rows, self.n_cols, self.out_cap, self.fp,
                  self.plan, self.dist_plans))
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, *aux)
+        key, row_nnz, seg, nnz, slot, a_idx, b_idx = leaves
+        return cls(key, row_nnz, seg, nnz, *aux, slot=slot, a_idx=a_idx,
+                   b_idx=b_idx)
 
     @property
     def batched(self) -> bool:
@@ -256,9 +280,25 @@ def make_structure(a: EllRows, b: EllCols, *, out_cap: Optional[int] = None,
                                    out_cap=out_cap, backend=plan.backend,
                                    tile=tile, slack=slack))
                 for s in schedules)
-    return SpgemmStructure(key=key, row_nnz=row_nnz, seg=seg, nnz=nnz,
-                           n_rows=a.n_rows, n_cols=b.n_cols, out_cap=out_cap,
-                           fp=fp, plan=plan, dist_plans=dist_plans)
+    return with_slots(
+        SpgemmStructure(key=key, row_nnz=row_nnz, seg=seg, nnz=nnz,
+                        n_rows=a.n_rows, n_cols=b.n_cols, out_cap=out_cap,
+                        fp=fp, plan=plan, dist_plans=dist_plans), a, b)
+
+
+def with_slots(st: SpgemmStructure, a: EllRows,
+               b: EllCols) -> SpgemmStructure:
+    """``st`` with each product lane's output slot for ``(a, b)``'s index
+    planes, and the planes (module docstring); ``st`` unchanged where its
+    plan streams. Found once by the numeric phase's own search, so the
+    cached slots are the ones every call would find."""
+    if st.plan is not None and st.plan.backend == "stream":
+        return st
+    from repro.core.spgemm import lane_slots
+    with _obs.span("structure.slots", lanes=int(a.idx.size * b.idx.shape[1])):
+        slot = _obs.sync(lane_slots(a.idx, b.idx, st.key, n_cols=st.n_cols,
+                                    out_cap=st.out_cap))
+    return dataclasses.replace(st, slot=slot, a_idx=a.idx, b_idx=b.idx)
 
 
 def make_structure_batched(a: EllRows, b: EllCols, *,

@@ -356,3 +356,158 @@ def test_engine_level_structure_cache(rng, tmp_path):
         structure_cache_size=4, structure_cache_dir=str(tmp_path)))
     eng2.spgemm(a, b)
     assert eng2.cache_stats()["disk_hits"] == 1
+
+
+# ---------------------------------------------------------------------------
+# Cached per-lane slots
+# ---------------------------------------------------------------------------
+
+SLOT_BACKENDS = tuple(b for b in BACKENDS if b != "stream")
+
+
+def _no_slots(st):
+    return dataclasses.replace(st, slot=None, a_idx=None, b_idx=None)
+
+
+def _slot_counters():
+    from repro.obs import metrics
+    c = metrics.snapshot()["counters"]
+    return (c.get("spgemm.numeric.slot_hits", 0),
+            c.get("spgemm.numeric.slot_searches", 0))
+
+
+@pytest.fixture
+def obs_on():
+    import repro.obs
+    repro.obs.enable(reset=True)
+    yield repro.obs
+    repro.obs.disable()
+    repro.obs.reset()
+
+
+def _stale_same_shape(a, ad, bd):
+    """A's pattern with one entry moved down its column to a row whose
+    product with B reaches an output coordinate C does not have: the ELL
+    shapes stay, the index plane changes, and a valid product misses."""
+    sa, sb = ad != 0, bd != 0
+    c = sa.astype(np.int64) @ sb.astype(np.int64) > 0
+    for k, j in np.argwhere(sb):
+        rows = np.flatnonzero(~sa[:, k] & ~c[:, j])
+        if sa[:, k].any() and rows.size:
+            out = ad.copy()
+            i0 = np.flatnonzero(sa[:, k])[0]
+            out[rows[0], k], out[i0, k] = out[i0, k], 0.0
+            a2 = ell_rows_from_dense(jnp.asarray(out), a.val.shape[0])
+            assert a2.idx.shape == a.idx.shape
+            assert not np.array_equal(np.asarray(a2.idx), np.asarray(a.idx))
+            return a2
+    raise AssertionError("no entry to move")
+
+
+@pytest.mark.parametrize("backend", SLOT_BACKENDS)
+def test_cached_slots_bitident_to_search(rng, backend, obs_on):
+    a, b, ad, _ = _pair(rng)
+    st = make_structure(a, b, backend=backend)
+    assert st.slot.shape == (a.idx.size * b.idx.shape[1],)
+    assert st.slot.dtype == jnp.int32
+    a2 = ell_rows_from_dense(jnp.asarray(ad * 3), a.val.shape[0])
+    for ops in ((a, b), (a2, b)):
+        cached = spgemm_coo_numeric(*ops, st, check=True)
+        searched = spgemm_coo_numeric(*ops, _no_slots(st), check=True)
+        assert _coo_eq(cached, searched)
+        assert _coo_eq(cached, spgemm_coo(*ops, plan=st.plan, check=True))
+    assert _slot_counters() == (2, 2)
+
+
+def test_stale_same_shape_structure_searches_and_poisons(rng, obs_on):
+    from repro.core.accumulate import AccumulatorOverflow
+    a, b, ad, bd = _pair(rng)
+    st = make_structure(a, b)
+    a2 = _stale_same_shape(a, ad, bd)
+    stale = spgemm_coo_numeric(a2, b, st, validate=False)
+    assert _slot_counters() == (0, 1)            # the search branch
+    assert int(stale.ngroups) > st.out_cap        # poisoned past cap
+    with pytest.raises(AccumulatorOverflow):
+        spgemm_coo_numeric(a2, b, st, validate=False, check=True)
+    assert _coo_eq(stale, spgemm_coo_numeric(a2, b, _no_slots(st),
+                                             validate=False))
+
+
+def _wave_results(a, b):
+    from repro.serve import SparseGemmBatcher
+    cache = StructureCache(capacity=4)
+    bt = SparseGemmBatcher(cache, max_slots=2)
+    a2 = ell_rows_from_dense(a.to_dense() * 2, a.val.shape[0])
+    rids = [bt.submit(a, b), bt.submit(a2, b)]
+    res = bt.flush()
+    return res[rids[0]]
+
+
+@pytest.mark.parametrize("kind", ["stream", "batched", "wave", "disk"])
+def test_structures_without_cached_slots_match_cold(rng, tmp_path, kind):
+    a, b, ad, bd = _pair(rng)
+    if kind == "stream":
+        st = make_structure(a, b, backend="stream")
+        assert st.slot is None and st.a_idx is None and st.b_idx is None
+        got = spgemm_coo_numeric(a, b, st, check=True)
+        ref = spgemm_coo(a, b, plan=st.plan, check=True)
+    elif kind == "batched":
+        ab = jax.tree_util.tree_map(lambda x: x[None], a)
+        bb = jax.tree_util.tree_map(lambda x: x[None], b)
+        st = make_structure_batched(ab, bb)
+        assert st.slot is None
+        got = spgemm_coo_numeric_batched(ab, bb, st, check=True)
+        ref = spgemm_coo_batched(ab, bb, plan=dataclasses.replace(
+            st.plan, fp=None), check=True)
+    elif kind == "wave":
+        got = _wave_results(a, b)
+        ref = spgemm_coo(a, b, out_cap=make_structure(a, b).out_cap)
+    else:
+        fresh = StructureCache(capacity=4, cache_dir=str(tmp_path)).get(a, b)
+        c2 = StructureCache(capacity=4, cache_dir=str(tmp_path))
+        st = c2.get(a, b)
+        assert c2.stats()["disk_hits"] == 1
+        np.testing.assert_array_equal(np.asarray(st.slot),
+                                      np.asarray(fresh.slot))
+        got = spgemm_coo_numeric(a, b, st, check=True)
+        ref = spgemm_coo(a, b, plan=st.plan, check=True)
+    assert _coo_eq(got, ref)
+
+
+def test_slot_counters_once_per_call_only_when_tracing(rng):
+    import repro.obs
+    a, b, _, _ = _pair(rng)
+    st = make_structure(a, b, backend="sort")
+    st_stream = make_structure(a, b, backend="stream")
+    repro.obs.disable()
+    repro.obs.reset()
+    try:
+        spgemm_coo_numeric(a, b, st)
+        assert _slot_counters() == (0, 0)
+        repro.obs.enable(reset=True)
+        for expected in ((1, 0), (2, 0)):
+            spgemm_coo_numeric(a, b, st)
+            assert _slot_counters() == expected
+        spgemm_coo_numeric(a, b, _no_slots(st))
+        spgemm_coo_numeric(a, b, st_stream)
+        assert _slot_counters() == (2, 2)
+        repro.obs.disable()
+        spgemm_coo_numeric(a, b, st)
+        assert _slot_counters() == (2, 2)
+    finally:
+        repro.obs.disable()
+        repro.obs.reset()
+
+
+def test_structure_with_slots_is_a_jit_argument(rng):
+    a, b, ad, _ = _pair(rng)
+    st = make_structure(a, b)
+    leaves, tree = jax.tree_util.tree_flatten(st)
+    back = jax.tree_util.tree_unflatten(tree, leaves)
+    assert back.slot is st.slot and back.a_idx is st.a_idx
+    assert back.plan == st.plan and back.fp == st.fp
+    fn = jax.jit(lambda a, b, st: spgemm_coo_numeric(a, b, st,
+                                                     validate=False))
+    a2 = ell_rows_from_dense(jnp.asarray(ad * 2), a.val.shape[0])
+    for ops in ((a, b), (a2, b)):
+        assert _coo_eq(fn(*ops, st), spgemm_coo_numeric(*ops, st))
