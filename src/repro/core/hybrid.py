@@ -58,10 +58,13 @@ class HybridCols:
 
 
 def ell_width_rule(nnz_per_lane: np.ndarray) -> int:
-    """Paper's boundary: k = ceil(mean + std) of per-lane non-zero counts."""
+    """Paper's boundary: k = ceil(mean + std) of per-lane non-zero counts,
+    and never wider than the widest lane (slots past it would stay empty,
+    and a width past the lane's length cannot be condensed into)."""
     nnz_av = float(np.mean(nnz_per_lane))
     sigma = float(np.std(nnz_per_lane))
-    return max(1, int(np.ceil(nnz_av + sigma)))
+    widest = int(np.max(nnz_per_lane))
+    return max(1, min(int(np.ceil(nnz_av + sigma)), widest))
 
 
 def split_rows_hybrid(a: jax.Array, k: int, coo_cap: int) -> HybridRows:

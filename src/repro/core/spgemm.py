@@ -92,6 +92,14 @@ def _poison_overflow(coo: Coo, dropped: jax.Array) -> Coo:
                ngroups=ng)
 
 
+def _count_accumulate(backend: str) -> None:
+    """One accumulate call on ``backend``, for the planner's share of
+    ``'search'`` (counted only while tracing is on)."""
+    _obs_metrics.inc("spgemm.accumulate.calls")
+    if backend == "search":
+        _obs_metrics.inc("spgemm.accumulate.search_calls")
+
+
 def accumulate_stream(row: jax.Array, col: jax.Array, val: jax.Array,
                       out_cap: int, n_rows: int, n_cols: int, *,
                       backend: str = "sort", tile: int = 4096,
@@ -112,12 +120,15 @@ def accumulate_stream(row: jax.Array, col: jax.Array, val: jax.Array,
     stream; ``spgemm_coo(accumulator='stream')`` avoids even that.
 
     Instrumented (repro.obs): a ``spgemm.accumulate`` span with a device
-    sync, whose measured µs feeds the planner est-vs-measured ledger —
-    disabled tracing takes the bare dispatch path untouched.
+    sync, whose measured µs feeds the planner est-vs-measured ledger, and
+    the counters ``spgemm.accumulate.calls`` and, on the ``'search'``
+    backend, ``spgemm.accumulate.search_calls`` — disabled tracing takes the
+    bare dispatch path untouched.
     """
     if not _obs.is_enabled():
         return _accumulate_impl(row, col, val, out_cap, n_rows, n_cols,
                                 backend=backend, tile=tile, plan=plan)
+    _count_accumulate(backend)
     with _obs.span("spgemm.accumulate", backend=backend,
                    lanes=int(row.size), out_cap=int(out_cap)) as sp:
         coo = _accumulate_impl(row, col, val, out_cap, n_rows, n_cols,
@@ -155,7 +166,8 @@ def _accumulate_impl(row: jax.Array, col: jax.Array, val: jax.Array,
         return _coo_from_merged(key, tot, out_cap, n_rows, n_cols)
     if backend == "search":
         # Paper Alg. 1 / Fig. 11: emit the sorted unique keys, align every
-        # product against them (kernels.insitu_search) — values are never
+        # product against them, land the values (kernels.insitu_search; the
+        # spans spgemm.accumulate.emit/.align/.land) — values are never
         # sorted. Truncation keeps the first out_cap unique keys and flags
         # via nnz > out_cap, exactly the 'sort' backend's contract; the
         # backend never internally drops, so no poisoning applies.
@@ -271,6 +283,7 @@ def spgemm_coo(a: EllRows, b: EllCols, out_cap="auto", *,
         scap = plan.stream_cap if plan is not None else None
         grp = plan.stream_group if plan is not None else 1
         if _obs.is_enabled():
+            _count_accumulate("stream")
             with _obs.span("spgemm.accumulate", backend="stream",
                            lanes=a.k * a.n_cols * b.k,
                            out_cap=int(out_cap)) as sp:

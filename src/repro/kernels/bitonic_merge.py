@@ -110,13 +110,10 @@ def _compare_exchange(key, val, d: int, keep_min, lane, roll):
     """One network stage: exchange with the lane at distance ``d``.
 
     Equal keys are the common case here (duplicate coordinates!) — tie-break
-    toward the lower lane so both values survive the exchange. ``val`` may be
-    None (key-only networks).
+    toward the lower lane so both values survive the exchange.
     """
     pk = _partner(key, d, lane, roll)
     new_key = jnp.where(keep_min, jnp.minimum(key, pk), jnp.maximum(key, pk))
-    if val is None:
-        return new_key, None
     pv = _partner(val, d, lane, roll)
     is_lo = jnp.bitwise_and(lane, d) == 0
     take_self_min = jnp.logical_or(
@@ -126,10 +123,10 @@ def _compare_exchange(key, val, d: int, keep_min, lane, roll):
     return new_key, jnp.where(keep_min, vmin, vmax)
 
 
-def _bitonic_sort_rows(key, val=None, *, tile: int | None = None,
+def _bitonic_sort_rows(key, val, *, tile: int | None = None,
                        roll=jnp.roll):
     """Full ascending bitonic sort of every power-of-2 tile (one tile per
-    row by default). ``val`` rides along; None sorts keys only."""
+    row by default); ``val`` rides along."""
     tile = tile or key.shape[-1]
     lane = _lanes(key, tile)
     for stage in range(int(math.log2(tile))):   # bitonic runs of 2^(s+1)
@@ -142,7 +139,7 @@ def _bitonic_sort_rows(key, val=None, *, tile: int | None = None,
     return key, val
 
 
-def _bitonic_merge_rows(key, val=None, *, tile: int | None = None,
+def _bitonic_merge_rows(key, val, *, tile: int | None = None,
                         roll=jnp.roll):
     """Ascending merge of *bitonic* tiles: the final log₂ tile stages."""
     tile = tile or key.shape[-1]
@@ -172,18 +169,13 @@ def _segmented_total_rows(key, val, *, tile: int | None = None,
 
 def _merge_pairs(key, val, run: int):
     """One tree level as XLA ops: merge adjacent sorted runs of length
-    ``run`` into sorted runs of ``2·run`` with run-tail totals (``val`` None
-    merges keys only)."""
+    ``run`` into sorted runs of ``2·run`` with run-tail totals."""
     k2 = key.reshape(-1, 2, run)
     key = jnp.concatenate([k2[:, 0], jnp.flip(k2[:, 1], axis=-1)], axis=-1)
-    if val is not None:
-        v2 = val.reshape(-1, 2, run)
-        val = jnp.concatenate([v2[:, 0], jnp.flip(v2[:, 1], axis=-1)],
-                              axis=-1)
+    v2 = val.reshape(-1, 2, run)
+    val = jnp.concatenate([v2[:, 0], jnp.flip(v2[:, 1], axis=-1)], axis=-1)
     key, val = _bitonic_merge_rows(key, val)
-    if val is not None:
-        val = _segmented_total_rows(key, val).reshape(-1)
-    return key.reshape(-1), val
+    return key.reshape(-1), _segmented_total_rows(key, val).reshape(-1)
 
 
 def merge_coalesce_pair(key_a, val_a, key_b, val_b):
@@ -210,13 +202,6 @@ def _make_sort_kernel(tile: int):
         key_out_ref[...] = key
         val_out_ref[...] = _segmented_total_rows(key, val, tile=tile,
                                                  roll=_roll_vmem)
-    return kernel
-
-
-def _make_key_sort_kernel(tile: int):
-    def kernel(key_ref, key_out_ref):
-        key_out_ref[...] = _bitonic_sort_rows(key_ref[...], tile=tile,
-                                              roll=_roll_vmem)[0]
     return kernel
 
 

@@ -11,14 +11,17 @@ column per step, high→low, keeping only active rows whose current bit is 0
 On TPU the word-line parallelism maps to VREG lanes: each of the 32 steps is
 one vectorized mask update over the (n/128, 128) tile in VMEM.
 ``_minima_kernel`` is the *faithful* Alg. 1 (mask of argmin rows + iterated
-extraction); ``emit_sorted_unique`` batches its emission the way
-bitonic_merge batches the full accumulation — a key-only compare-exchange
-network produces the same sorted-unique key list (Fig. 11c) in one pass
-instead of nnz_C scans.
-``align_keys`` is the second half of the paper's in-situ search: every
-product coordinate is located in that sorted list by a gather-free
-vectorized search (a CAM lookup on hardware; here a broadcast compare /
-``searchsorted`` per realization).
+extraction); ``emit_sorted_unique`` batches its emission: one XLA sort of
+the stream and a run-head compaction produce the same sorted-unique key
+list (Fig. 11c) in one pass instead of nnz_C scans, in a program whose
+size does not depend on the stream's length.
+Alignment is the second half of the paper's in-situ search: every product
+coordinate is located in that sorted list (a CAM lookup on hardware).
+``emit_ranked`` sorts (key, lane) so the rank of each sorted lane among
+the unique keys falls out of the run heads, and ``align_ranked`` hands each
+product its slot back through the sort's permutation: no search at all.
+``align_keys`` locates keys it did not emit itself (the faithful path's):
+a gather-free broadcast compare on short lists, ``searchsorted`` beyond.
 
 Realization selection follows the repo-wide ``resolve_mode`` contract:
 ``interpret=None`` (the default) runs the compiled Pallas kernels on TPU and
@@ -33,8 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bitonic_merge import (LANES, _make_key_sort_kernel, _merge_pairs,
-                            next_pot, tile_call)
+from .bitonic_merge import LANES, next_pot
 from .platform import resolve_mode
 
 KEY_INVALID = jnp.iinfo(jnp.int32).max
@@ -133,45 +135,42 @@ def search_emit_sorted(v: jax.Array, max_unique: int,
 
 
 # ---------------------------------------------------------------------------
-# Batched emission: the sorted-unique key list in one key-only network pass
+# Batched emission: the sorted-unique key list from one sort of the stream
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _emit_sort_keys_pallas(key: jax.Array, *, tile: int,
-                           interpret: bool) -> jax.Array:
-    """Globally sort a power-of-2 key stream: the key-only bitonic network
-    per VMEM tile (no value lane to carry: emission only needs the keys,
-    alignment recovers each product's slot afterwards), then pairwise
-    key-only merges up the tree as XLA ops (bitonic_merge's blocking)."""
+@functools.partial(jax.jit, static_argnames=("out_cap",))
+def emit_ranked(key: jax.Array, *, out_cap: int):
+    """The batched emission, with what alignment needs: one sort of (key,
+    lane) gives the sorted unique keys and, for every sorted lane, its rank
+    among them, so each product's slot is read off the sort's own
+    permutation instead of searched for.
+
+    The run heads (the first lane of every equal-key run, exactly the
+    emission order of the iterated Alg. 1 scan) are packed densely by a
+    key-only sort that sends every other lane to the KEY_INVALID tail: on
+    TPU a sort of the stream costs a third of a scatter of it. Returns
+    ``(uk, nnz, perm, rank)``: ``uk`` and ``nnz`` as
+    ``emit_sorted_unique``; ``perm[i]`` the stream lane at sorted position
+    ``i``; ``rank[i]`` the number of unique keys below that lane's key
+    (``nnz`` for KEY_INVALID lanes). XLA sorts whatever the stream length,
+    so the compiled program does not grow with it."""
     (n,) = key.shape
-    t = min(tile, n)
-    (key,) = tile_call(_make_key_sort_kernel(t), t, [key],
-                       interpret=interpret)
-    run = t
-    while run < n:
-        key, _ = _merge_pairs(key, None, run)
-        run *= 2
-    return key
-
-
-def _unique_heads(ks: jax.Array, out_cap: int):
-    """Run-head compaction of a sorted key stream: the first lane of every
-    equal-key run, scattered densely — exactly the emission order of the
-    iterated Alg. 1 scan. Returns (uk (out_cap,) ascending KEY_INVALID-
-    padded, nnz = TRUE unique count, > out_cap when truncated)."""
+    ks, perm = jax.lax.sort((key, jnp.arange(n, dtype=jnp.int32)),
+                            num_keys=1, is_stable=False)
     prev = jnp.concatenate([jnp.full((1,), -1, ks.dtype), ks[:-1]])
-    head = jnp.logical_and(ks != prev, ks != KEY_INVALID)
-    nnz = jnp.sum(head).astype(jnp.int32)
-    dst = jnp.minimum(jnp.where(head, jnp.cumsum(head) - 1, out_cap), out_cap)
-    uk = (jnp.full((out_cap + 1,), KEY_INVALID, jnp.int32)
-          .at[dst].set(jnp.where(head, ks, KEY_INVALID)))[:out_cap]
-    return uk, nnz
+    valid = ks != KEY_INVALID
+    head = jnp.logical_and(ks != prev, valid)
+    run = jnp.cumsum(head, dtype=jnp.int32)
+    uk = jnp.sort(jnp.where(head, ks, KEY_INVALID))
+    uk = jnp.pad(uk, (0, max(0, out_cap - n)),
+                 constant_values=KEY_INVALID)[:out_cap]
+    return uk, run[-1], perm, run - valid.astype(jnp.int32)
 
 
 def emit_sorted_unique(key: jax.Array, out_cap: int, *,
                        interpret: bool | None = None,
-                       faithful: bool = False, tile: int = 4096):
+                       faithful: bool = False):
     """The ``'search'`` backend's emission phase: the sorted unique keys of
     a packed product stream — the paper's "sorted list of the output
     matrix" (Fig. 11c) that every product is subsequently aligned against.
@@ -181,42 +180,52 @@ def emit_sorted_unique(key: jax.Array, out_cap: int, *,
     truncation — the first ``out_cap`` unique keys are kept, matching the
     'sort' backend's truncation order).
 
-    ``faithful=True`` runs the literal iterated Alg. 1 scan (O(out_cap·32)
-    minima searches) instead of the batched key-only sort — the two are
-    bit-identical; the faithful path's ``nnz`` reports ``out_cap + 1`` when
-    truncated (a floor: the scan stops emitting at ``out_cap``, but any
-    leftover active row still flags the overflow).
+    The batched emission is ``emit_ranked``'s, on every backend: its
+    program is the same size at any stream length. ``faithful=True`` runs
+    the literal iterated Alg. 1 scan (O(out_cap·32) minima searches, the
+    Pallas kernel on TPU) instead — the two are bit-identical; the
+    faithful path's ``nnz`` reports ``out_cap + 1`` when truncated (a
+    floor: the scan stops emitting at ``out_cap``, but any leftover active
+    row still flags the overflow).
     """
+    if not faithful:
+        return emit_ranked(key, out_cap=out_cap)[:2]
     mode = resolve_mode(interpret)
-    if faithful:
-        if mode == "xla":
-            mask_fn = minima_mask_xla
-        else:
-            mask_fn = functools.partial(_minima_mask_pallas_jit,
-                                        interpret=mode == "interpret")
-
-        def step(v_cur, _):
-            mask = mask_fn(v_cur)
-            any_left = jnp.any(mask)
-            val = jnp.min(jnp.where(mask, v_cur, KEY_INVALID))
-            v_next = jnp.where(mask, KEY_INVALID, v_cur)
-            return v_next, jnp.where(any_left, val, KEY_INVALID)
-
-        v_final, uk = jax.lax.scan(step, key, None, length=out_cap)
-        emitted = jnp.sum(uk != KEY_INVALID).astype(jnp.int32)
-        leftover = jnp.any(v_final != KEY_INVALID)
-        return uk, emitted + leftover.astype(jnp.int32)
     if mode == "xla":
-        ks = jnp.sort(key)
+        mask_fn = minima_mask_xla
     else:
-        ks = _emit_sort_keys_pallas(key, tile=tile,
+        mask_fn = functools.partial(_minima_mask_pallas_jit,
                                     interpret=mode == "interpret")
-    return _unique_heads(ks, out_cap)
+
+    def step(v_cur, _):
+        mask = mask_fn(v_cur)
+        any_left = jnp.any(mask)
+        val = jnp.min(jnp.where(mask, v_cur, KEY_INVALID))
+        v_next = jnp.where(mask, KEY_INVALID, v_cur)
+        return v_next, jnp.where(any_left, val, KEY_INVALID)
+
+    v_final, uk = jax.lax.scan(step, key, None, length=out_cap)
+    emitted = jnp.sum(uk != KEY_INVALID).astype(jnp.int32)
+    leftover = jnp.any(v_final != KEY_INVALID)
+    return uk, emitted + leftover.astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
-# Alignment: locate every product key in the sorted unique list, gather-free
+# Alignment: every product key's slot in the sorted unique list
 # ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("out_cap",))
+def align_ranked(perm: jax.Array, rank: jax.Array, *, out_cap: int):
+    """``align_keys``'s contract for the stream ``emit_ranked`` sorted, from
+    its permutation: the rank of each sorted lane, clipped to ``out_cap``,
+    goes back to the lane it came from by one sort keyed on the
+    permutation (on TPU half the time of scattering it there). Returns
+    ``(slot, hit)`` in stream order; a lane's key is among the first
+    ``out_cap`` unique keys exactly when its rank is below ``out_cap``."""
+    _, slot = jax.lax.sort((perm, jnp.minimum(rank, out_cap)), num_keys=1,
+                           is_stable=False)
+    return slot, slot < out_cap
 
 
 def _make_align_kernel(u: int, chunk: int):

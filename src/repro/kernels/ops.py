@@ -12,6 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace as _obs
+
 from . import (fused_sccp_stream, hash_accum, insitu_search, platform,
                radix_bucket)
 from .bitonic_merge import (KEY_INVALID, MAX_KERNEL_TILE, next_pot,
@@ -120,20 +122,41 @@ def _unpackable(n_rows: int, n_cols: int):
         "automatically")
 
 
+@functools.partial(jax.jit, static_argnames=("n_rows", "n_cols", "out_cap"))
+def _search_emit(row, col, val, *, n_rows: int, n_cols: int, out_cap: int):
+    """Emission program: pack the stream's keys, sort them with their lanes
+    and emit the sorted unique keys (``insitu_search.emit_ranked``)."""
+    key, _ = _packed_stream(row, col, val, n_rows, n_cols)
+    return (key,) + insitu_search.emit_ranked(key, out_cap=out_cap)
+
+
+@functools.partial(jax.jit, static_argnames=("out_cap",))
+def _search_land(val, key, slot, hit, *, out_cap: int):
+    """Land program: sum every valid product whose key was emitted into its
+    slot; the rest go to the dump slot ``out_cap``."""
+    v = pad_to(val.reshape(-1), 0, key.shape[0], 0.0)
+    ok = jnp.logical_and(key != KEY_INVALID, hit)
+    return jax.ops.segment_sum(jnp.where(ok, v, 0),
+                               jnp.where(ok, slot, out_cap),
+                               num_segments=out_cap + 1)[:out_cap]
+
+
 def search_merge(row, col, val, n_rows: int, n_cols: int, *,
                  out_cap: int, interpret: bool | None = None,
                  faithful: bool = False):
     """The paper's in-situ-search accumulation (Alg. 1 / Fig. 11): emit the
-    sorted unique coordinate list, then align every product against it.
+    sorted unique coordinate list, align every product against it, land
+    the values in their slots.
 
-    Two passes over the packed stream: ``insitu_search.emit_sorted_unique``
-    produces the sorted unique keys (batched key-only network, or the
-    literal iterated Alg. 1 scan with ``faithful=True``), and
-    ``insitu_search.align_keys`` locates each product's slot in that list
-    (CAM-style broadcast compare on the Pallas path, ``searchsorted`` on
-    XLA) — no re-sort of the value lanes at all, which is exactly where
-    this backend beats 'sort' on duplicate-heavy streams. One segment-sum
-    lands the values.
+    Three programs, each compiled once per shape: emit
+    (``insitu_search.emit_ranked``: one sort of the packed keys with their
+    lanes, the run heads giving the sorted unique keys and each lane's
+    rank), align (``insitu_search.align_ranked``: each product's slot
+    through the sort's permutation) and land (one segment-sum of the values,
+    which are never sorted). ``faithful=True`` emits by the literal iterated
+    Alg. 1 scan instead and aligns with ``insitu_search.align_keys``. While
+    tracing is on, each phase is a span (``spgemm.accumulate.emit``,
+    ``.align``, ``.land``) that closes on a device sync.
 
     Returns ``(uk, sums, nnz)``: the (out_cap,) sorted unique keys with
     KEY_INVALID padding, the per-slot value totals, and the TRUE unique
@@ -142,17 +165,26 @@ def search_merge(row, col, val, n_rows: int, n_cols: int, *,
     order). Coordinate spaces ≥ 2³¹ can't pack and raise, like the other
     packed-key backends (spgemm_coo reroutes those to 'sort').
     """
-    packed = _packed_stream(row, col, val, n_rows, n_cols)
-    if packed is None:
+    if n_rows * n_cols >= jnp.iinfo(jnp.int32).max:
         _unpackable(n_rows, n_cols)
-    key, v = packed
-    uk, nnz = insitu_search.emit_sorted_unique(
-        key, out_cap, interpret=interpret, faithful=faithful)
-    slot, hit = insitu_search.align_keys(key, uk, interpret=interpret)
-    ok = jnp.logical_and(key != KEY_INVALID, hit)
-    slot = jnp.where(ok, slot, out_cap)
-    sums = jax.ops.segment_sum(jnp.where(ok, v, 0), slot,
-                               num_segments=out_cap + 1)[:out_cap]
+    with _obs.span("spgemm.accumulate.emit", lanes=int(row.size),
+                   out_cap=int(out_cap)):
+        if faithful:
+            key, _ = _packed_stream(row, col, val, n_rows, n_cols)
+            uk, nnz = _obs.sync(insitu_search.emit_sorted_unique(
+                key, out_cap, interpret=interpret, faithful=True))
+        else:
+            key, uk, nnz, perm, rank = _obs.sync(_search_emit(
+                row, col, val, n_rows=n_rows, n_cols=n_cols, out_cap=out_cap))
+    with _obs.span("spgemm.accumulate.align"):
+        if faithful:
+            slot, hit = insitu_search.align_keys(key, uk, interpret=interpret)
+        else:
+            slot, hit = insitu_search.align_ranked(perm, rank,
+                                                   out_cap=out_cap)
+        _obs.sync(slot)
+    with _obs.span("spgemm.accumulate.land"):
+        sums = _obs.sync(_search_land(val, key, slot, hit, out_cap=out_cap))
     return uk, sums, nnz
 
 

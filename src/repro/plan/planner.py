@@ -16,9 +16,10 @@ backends:
             backend that never materializes the (k_a, n, k_b) product
             stream; its intermediate is O(n·k_b + stream_cap)
   search  — the paper's in-situ-search accumulation (kernels/insitu_search):
-            key-only emission of the sorted unique coordinates, then every
-            product aligned against that list — values are never sorted,
-            so the win grows with the duplicate ratio S / nnz(C)
+            emission of the sorted unique coordinates by one sort of
+            (key, lane), every product aligned to its slot through that
+            sort's permutation, one segment-sum landing the values, which
+            are never sorted
 
 The model is also **memory-aware**: every backend's modeled intermediate
 bytes go into ``Plan.est`` (``interm_*`` — the materialized un-accumulated
@@ -77,11 +78,24 @@ INTERPRET_PENALTY = 50.0   # Pallas interpret-mode slowdown off-TPU
 # scalar comparator (STREAM_SORT_C scales its per-element unit down).
 SORT_TRAFFIC = 1.5
 STREAM_SORT_C = 0.5
-# 'search' sorts KEYS ONLY for its emission phase (4 B/lane, scalar
-# comparator — no value lanes ride the network), then aligns each product
-# against the nnz(C)-long unique list (log2(nnz_C) levels, not log2(S)).
-SEARCH_SORT_C = 0.4
-ALIGN_C = 0.5
+# 'search' runs three single-key sorts of the padded stream — (key, lane)
+# for its emission, the run heads' keys for the compaction, (lane, rank)
+# for the alignment — and one segment-sum for its landing. Fitted on a TPU
+# v5e at the HPCG 27-point stencil's A·Aᵀ (32×32×40 grid: S = 2^25 lanes,
+# 5.88 products per output), in the units of 'sort''s term there: the
+# 'sort' accumulate took 0.990 s for its SORT_TRAFFIC · S · log2 S units;
+# the emission 0.167 s, the alignment 0.075 s, the landing (a segment-sum
+# dearer per lane than any sort) 0.369 s. The constants stand off the chip
+# too, where nothing was fitted. Only the 'sort' and 'search' terms share
+# these units: the other backends' constants, SEGSUM_C of 'hash''s same
+# segment-sum among them, are the older relative vector-op units and were
+# never fitted on the chip. The term has no nnz(C) part, since each of its
+# four passes runs over the padded stream, so past about 2^13 lanes
+# 'search' ranks below 'sort' at any duplicate ratio; it was measured at
+# 5.88 products per output only.
+SEARCH_SORT_C = 0.25    # emission, per element per log2 level
+ALIGN_C = 0.11          # alignment, per element per log2 level
+LAND_C = 14.0           # landing, per element
 # Fixed per-scan-step floor of the streaming engine (dispatch + carry +
 # compaction bookkeeping), in the same per-element units — measured ≈ a
 # few hundred µs off-TPU. This is what the planner's stream_group
@@ -191,14 +205,10 @@ def _backend_costs(s: MatrixStats, stream_pot: int, tile: int,
     merge = CE_C * mrg * (math.log2(mrg) + 1)
     cost["stream"] = n_steps * (tile_sort + merge + SCAN_STEP_C)
 
-    # search: key-only emission sort + per-product alignment against the
-    # nnz(C) unique keys + one segment-sum. Both realizations are compiled
-    # (XLA sort/searchsorted off-TPU, the Pallas network/CAM kernel on TPU)
-    # so no interpreter penalty applies — the dup ratio S/nnz_C is what
-    # moves the alignment term below the full re-sort.
-    lu = max(1.0, math.log2(max(2.0, float(s.nnz_c))))
-    cost["search"] = (SEARCH_SORT_C * XLA_SORT_C * S * ls
-                      + ALIGN_C * S * lu + SEGSUM_C * S)
+    # search: the emission's and the alignment's sorts + one segment-sum,
+    # all XLA programs on every platform, so no interpreter penalty.
+    cost["search"] = ((SEARCH_SORT_C + ALIGN_C) * XLA_SORT_C * S * ls
+                      + LAND_C * S)
     return cost
 
 
@@ -232,9 +242,11 @@ def _backend_interm_bytes(stream_lanes: int, stream_pot: int,
         "bucket": raw + packed + 8.0 * n_buckets * bucket_cap,
         "hash": raw + packed + 8.0 * n_blocks * block_cap,
         "stream": _stream_interm_bytes(tile_lanes, stream_cap),
-        # packed key+val copy, the key-only sorted copy (4 B/lane), and the
-        # unique-key list + slot sums the alignment scatters into
-        "search": raw + 12.0 * stream_pot + 8.0 * out_cap,
+        # per padded lane: the packed key, the sort's permutation and each
+        # lane's rank and slot (16 B), and the landing's segment-sum
+        # temporaries (about 20 B: its indices and values sorted); plus
+        # the unique keys and their sums
+        "search": raw + 36.0 * stream_pot + 8.0 * out_cap,
     }
 
 

@@ -60,9 +60,6 @@ CASES = {
         fused_sccp_stream.fused_slab_sort_pallas, n_cols=45_000,
         interpret=False),
         [((512,), F32), ((512,), I32), ((512, 8), F32), ((512, 8), I32)]),
-    "emit_sort_keys": (functools.partial(
-        insitu_search._emit_sort_keys_pallas, tile=4096, interpret=False),
-        [((4096,), I32)]),
     "align_keys": (functools.partial(insitu_search._align_keys_pallas_jit,
                                      interpret=False),
                    [((1 << 16,), I32), ((insitu_search.ALIGN_MAX_KEYS,),
@@ -92,3 +89,19 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
     compiled = jax.jit(fn).lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("emit", ["emit_sorted_unique", "emit_ranked"])
+def test_emission_code_does_not_grow_with_stream(emit, one_chip):
+    """The 'search' emission compiles to one program whose generated code
+    stays the same size as the stream grows (an unrolled merge tree adds a
+    level per doubling, and its code and compile memory with it)."""
+    fn = getattr(insitu_search, emit)
+
+    def code_bytes(log2_keys):
+        out_cap = 1 << (log2_keys - 2)
+        spec = jax.ShapeDtypeStruct((1 << log2_keys,), I32, sharding=one_chip)
+        lowered = jax.jit(lambda k: fn(k, out_cap=out_cap)).lower(spec)
+        return len(lowered.compile().as_text())
+
+    assert code_bytes(18) <= 2 * code_bytes(14)
