@@ -13,11 +13,19 @@ coordinate planes followed by a **segmented sum**. ``jax.lax.sort`` with
 at row = n_rows so they fall to the tail, exactly like the paper flipping the
 sign bit to invalidate consumed coordinates.
 
+The merge (``merge_sorted``) is one jitted program: the values land by one
+segmented sum over the run ids, and the run heads, already in output
+order, are packed to the front by one more unstable two-key (row, col)
+sort, every other lane parked at row = n_rows, rather than found by
+segment-min and gathered (on TPU a gather of the stream costs eight sorts
+of it). One path serves every coordinate space, int32-packable or not.
+
 The Pallas kernel (kernels/bitonic_merge.py) is the explicitly tiled
 in-VMEM version for coordinate spaces that fit 16-bit tiles.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -49,16 +57,26 @@ class AccumulatorOverflow(ValueError):
     """The true unique-coordinate count exceeded the static ``out_cap``."""
 
 
+@functools.partial(jax.jit, static_argnames=("out_cap", "n_rows", "n_cols"))
 def merge_sorted(row: jax.Array, col: jax.Array, val: jax.Array,
                  out_cap: int, n_rows: int, n_cols: int) -> Coo:
     """Coalesce a coordinate-sorted stream: sum runs of equal (row, col).
 
+    One jitted program per (stream length, ``out_cap``, shape). The values
+    land by one ``segment_sum`` over the run ids. The run heads carry the
+    output coordinates; the stream is sorted, so they already stand in
+    output order and only need packing to the front. That packing is one
+    unstable two-key sort of (row, col) with every other lane parked at
+    row = n_rows, the ``sort_by_coords`` trick, for every coordinate space
+    (no int32 key, so nothing to overflow). Slots from ``min(ngroups,
+    out_cap)`` on read INVALID.
+
     Static output size ``out_cap``; if the true number of unique coordinates
-    exceeds it the stored stream is truncated (callers size out_cap from
-    hwmodel / upper bounds) — but the returned ``Coo`` carries ``ngroups``,
-    the TRUE group count, so truncation is detectable (``coo.overflowed()``
-    in-graph, ``check_no_overflow`` on the host). This is the "on-chip
-    accumulator" epilogue of Fig. 11(c).
+    exceeds it the first ``out_cap`` groups are kept (callers size out_cap
+    from hwmodel / upper bounds) — but the returned ``Coo`` carries
+    ``ngroups``, the TRUE group count, so truncation is detectable
+    (``coo.overflowed()`` in-graph, ``check_no_overflow`` on the host). This
+    is the "on-chip accumulator" epilogue of Fig. 11(c).
     """
     valid = row >= 0
     new_grp = jnp.logical_or(row != jnp.roll(row, 1), col != jnp.roll(col, 1))
@@ -68,13 +86,16 @@ def merge_sorted(row: jax.Array, col: jax.Array, val: jax.Array,
     seg = jnp.where(valid, seg, out_cap)                      # park invalid
     seg = jnp.clip(seg, 0, out_cap)                           # truncate overflow
     sums = jax.ops.segment_sum(val, seg, num_segments=out_cap + 1)[:out_cap]
-    # representative coordinates per group = first element of each run
-    first = jnp.where(new_grp, jnp.arange(row.shape[0]), row.shape[0] - 1)
-    first_idx = jax.ops.segment_min(first, seg, num_segments=out_cap + 1)[:out_cap]
     ngroups = jnp.sum(new_grp)
     slot_ok = jnp.arange(out_cap) < ngroups
-    out_row = jnp.where(slot_ok, row[first_idx], INVALID).astype(jnp.int32)
-    out_col = jnp.where(slot_ok, col[first_idx], INVALID).astype(jnp.int32)
+    head_row, head_col = jax.lax.sort(
+        (jnp.where(new_grp, row, n_rows), jnp.where(new_grp, col, 0)),
+        num_keys=2, is_stable=False)
+    pad = (0, max(0, out_cap - row.shape[0]))
+    head_row = jnp.pad(head_row, pad)[:out_cap]
+    head_col = jnp.pad(head_col, pad)[:out_cap]
+    out_row = jnp.where(slot_ok, head_row, INVALID).astype(jnp.int32)
+    out_col = jnp.where(slot_ok, head_col, INVALID).astype(jnp.int32)
     out_val = jnp.where(slot_ok, sums, 0)
     return Coo(row=out_row, col=out_col, val=out_val, shape=(n_rows, n_cols),
                ngroups=ngroups.astype(jnp.int32))
@@ -86,8 +107,8 @@ def accumulate(row: jax.Array, col: jax.Array, val: jax.Array,
 
     Instrumented (repro.obs): ``spgemm.accumulate.sort`` and
     ``spgemm.accumulate.merge`` spans, each closing on a device sync while
-    tracing is on (outside jit both halves run op by op, so the host span
-    is what bounds their device time)."""
+    tracing is on (outside jit the sort runs op by op and the merge as its
+    own program, so the host span is what bounds their device time)."""
     with _obs.span("spgemm.accumulate.sort", lanes=int(row.size)):
         r, c, v = _obs.sync(sort_by_coords(row, col, val, n_rows))
     with _obs.span("spgemm.accumulate.merge", out_cap=int(out_cap)):

@@ -13,6 +13,7 @@ JAX's persistent compilation cache is off around these compiles: an entry
 written for a described chip cannot be read back without one.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,3 +106,22 @@ def test_emission_code_does_not_grow_with_stream(emit, one_chip):
         return len(lowered.compile().as_text())
 
     assert code_bytes(18) <= 2 * code_bytes(14)
+
+
+def test_merge_packs_heads_by_sort_not_gather(one_chip):
+    """The 'sort' accumulate's merge compiles for a v5e as one program whose
+    run heads are packed by a sort: no gather and no min-scatter (the
+    ``segment_min`` that used to find the heads) remain in it."""
+    from repro.core.accumulate import merge_sorted
+    n, (n_rows, n_cols) = 1 << 20, (44_609, 44_609)
+    specs = [jax.ShapeDtypeStruct((n,), d, sharding=one_chip)
+             for d in (I32, I32, F32)]
+    hlo = jax.jit(functools.partial(
+        merge_sorted, out_cap=n // 2, n_rows=n_rows, n_cols=n_cols)).lower(
+        *specs).compile().as_text()
+    assert " gather(" not in hlo
+    combiners = re.findall(r" scatter\(.*?to_apply=(%?[\w.\-]+)", hlo)
+    assert combiners                      # the value landing stays a scatter
+    for name in combiners:
+        body = re.search(re.escape(name) + r" [^{]*\{(.*?)\n\}", hlo, re.S)
+        assert body and "minimum(" not in body.group(1), name
